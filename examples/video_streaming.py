@@ -16,7 +16,7 @@ import time
 
 import numpy as np
 
-from repro.configs.shelby import CONFIG, resolve_decode_matmul
+from repro.configs.shelby import CONFIG
 from repro.core.contract import ShelbyContract
 from repro.core.placement import SPInfo
 from repro.storage.blob import BlobLayout
@@ -36,7 +36,7 @@ for i in range(20):
     contract.register_sp(SPInfo(sp_id=i, stake=1000.0, dc=f"dc{i % 5}", rack=f"r{i % 4}"))
     sps[i] = StorageProvider(i)
 rpc = RPCNode("rpc0", contract, sps, layout, hedge=2, cache_chunksets=4,
-              decode_matmul=resolve_decode_matmul(CONFIG.decode_matmul))
+              decode_matmul=CONFIG.decode_matmul)
 client = ShelbyClient(contract, rpc)
 
 print(f"uploading 'video' ({layout.replication_overhead:.1f}x replication overhead)...")

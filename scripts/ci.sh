@@ -56,11 +56,7 @@ echo "== lint: simlint =="
 python -m repro.analysis --check
 
 echo "== tier-1: pytest =="
-# test_distributed_equivalence_8dev needs jax.shard_map, absent from the
-# pinned jax in this image (fails at seed too) — deselected so the gate
-# trips only on NEW failures.
-python -m pytest -q \
-    --deselect tests/test_sharding.py::test_distributed_equivalence_8dev
+python -m pytest -q
 
 echo "== scenario catalog freshness =="
 # docs/CATALOG.md is generated from the registry + the COMMITTED bench

@@ -7,28 +7,6 @@ from repro.core.audit import AuditParams
 from repro.storage.blob import BlobLayout
 
 
-def resolve_decode_matmul(choice: str = "auto"):
-    """Map a config string to the GF matmul the batched Clay decode uses.
-
-    * ``"numpy"``  -> ``None``: the pure-numpy GF(2^8) path (fastest on CPU).
-    * ``"pallas"`` -> the Pallas ``gf_matmul`` kernel (Mosaic on TPU;
-      interpret mode elsewhere, which is a slowdown — only force it to
-      exercise the kernel).
-    * ``"auto"``   -> pallas on a real TPU runtime, numpy otherwise.
-    """
-    if choice == "auto":
-        import jax
-
-        choice = "pallas" if jax.default_backend() == "tpu" else "numpy"
-    if choice == "numpy":
-        return None
-    if choice == "pallas":
-        from repro.kernels import ops
-
-        return ops.gf_matmul_np
-    raise ValueError(f"decode_matmul must be auto|numpy|pallas, got {choice!r}")
-
-
 @dataclasses.dataclass(frozen=True)
 class ShelbyConfig:
     layout: BlobLayout = BlobLayout(k=10, m=6, chunkset_bytes_target=10 * 1024 * 1024)
@@ -46,7 +24,7 @@ class ShelbyConfig:
     price_per_chunk_read: float = 1e-6
     storage_fee_per_gb_month: float = 0.023  # W, benchmarked against S3
     epochs_per_month: float = 30.0
-    decode_matmul: str = "auto"  # auto | numpy | pallas (see resolve_decode_matmul)
+    decode_matmul: str = "auto"  # auto | numpy | pallas (see kernels.ops.resolve_decode_matmul)
     # hot-cache policy per RPC node (LRU always; these add expiry/admission)
     rpc_cache_ttl_ms: float | None = None  # sim-clock TTL for decoded entries
     rpc_cache_admit_bytes: int | None = None  # skip caching decodes larger than this
@@ -178,7 +156,11 @@ class ShelbyConfig:
         )
 
     def resolve_decode_matmul(self):
-        return resolve_decode_matmul(self.decode_matmul)
+        """The Clay-decode GF matmul for the ``decode_matmul`` knob (see
+        ``kernels.ops.resolve_decode_matmul``)."""
+        from repro.kernels import ops
+
+        return ops.resolve_decode_matmul(self.decode_matmul)
 
 
 CONFIG = ShelbyConfig()

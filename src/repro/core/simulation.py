@@ -95,7 +95,7 @@ def run_sim(
     seed: int = 0,
     num_rpcs: int = 1,
     read_requests_per_epoch: int = 0,
-    decode_matmul=None,  # e.g. configs.shelby.resolve_decode_matmul("pallas")
+    decode_matmul="auto",  # RPCNode decode_matmul: auto | numpy | pallas
     admission=None,  # storage.rpc.AdmissionSpec: shed past saturation
     single_flight: bool = True,  # collapse concurrent same-chunkset misses
     background: BackgroundSpec | None = None,  # per-SP audit/repair budget

@@ -23,13 +23,10 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # TPU scratch memories (interpret mode accepts them too)
-    from jax.experimental.pallas import tpu as pltpu
-
-    _SCRATCH = lambda shape: pltpu.VMEM(shape, jnp.float32)
-except Exception:  # pragma: no cover
-    _SCRATCH = lambda shape: pl.MemorySpace.ANY  # type: ignore
+# TPU scratch memories (interpret mode accepts them too)
+_SCRATCH = lambda shape: pltpu.VMEM(shape, jnp.float32)
 
 NEG_INF = -1e30
 

@@ -12,10 +12,11 @@ from __future__ import annotations
 
 import argparse
 
+import jax
 import numpy as np
 
 from repro.configs import ALL_ARCHS, get, get_smoke
-from repro.configs.shelby import CONFIG, resolve_decode_matmul
+from repro.configs.shelby import CONFIG
 from repro.core.contract import ShelbyContract
 from repro.core.placement import SPInfo
 from repro.data.pipeline import BlobTokenDataset, write_token_corpus
@@ -33,8 +34,9 @@ def build_cluster(num_sps: int = 8, layout: BlobLayout | None = None,
                   num_rpcs: int = 1):
     """A simulated deployment fronted by the fleet-first client.
 
-    The batched Clay decode's GF matmul comes from `configs/shelby.py`
-    (numpy on CPU, the Pallas kernel on real TPU runtimes).
+    RPC node ``r`` decodes on JAX device ``r`` (round robin when there are
+    fewer devices than nodes), through the GF matmul `kernels/ops.py`
+    chooses for it: the Pallas kernel on a TPU, numpy on the CPU.
     """
     layout = layout or BlobLayout(k=4, m=2, chunkset_bytes_target=256 * 1024)
     contract = ShelbyContract()
@@ -44,10 +46,11 @@ def build_cluster(num_sps: int = 8, layout: BlobLayout | None = None,
         sps[i] = StorageProvider(
             i, service=ServiceSpec(slots=CONFIG.sp_service_slots)
         )
-    matmul = resolve_decode_matmul(CONFIG.decode_matmul)
+    devices = jax.devices()
     rpcs = [
         RPCNode(f"rpc{r}", contract, sps, layout, cache_chunksets=32,
-                decode_matmul=matmul,
+                decode_matmul=CONFIG.decode_matmul,
+                device=devices[r % len(devices)],
                 cache_ttl_ms=CONFIG.rpc_cache_ttl_ms,
                 cache_admit_bytes=CONFIG.rpc_cache_admit_bytes,
                 admission=CONFIG.admission(),

@@ -21,8 +21,10 @@ interleave on one global heap.  The synchronous entry points
 stay exactly as before for sequential callers.  Reads spanning several
 chunksets — even of *different blobs*, via ``read_items_detailed`` — take
 the **batched decode path**: chunksets with the same erasure pattern are
-Clay-decoded in one wide GF call (``ClayCode.decode_batch``, optionally
-through the Pallas ``gf_matmul`` kernel) instead of one-at-a-time numpy.
+Clay-decoded in one wide GF call (``ClayCode.decode_batch``) instead of
+one at a time.  Every decode, batched or not, goes through the GF matmul
+``kernels.ops.resolve_decode_matmul`` picks: the Pallas ``gf_matmul`` on
+the node's device when that is a TPU, numpy otherwise.
 
 Payments are **on delivery** (§2.2/§3.2): a chunk is paid through the
 RPC->SP micropayment channel only once it arrived AND verified against its
@@ -139,6 +141,8 @@ class ReadStats:
     fetch_ms_total: float = 0.0  # simulated clock, not wall time
     coalesced: int = 0  # misses that piggybacked on an in-flight fetch
     shed_requests: int = 0  # reads refused at admission (Overloaded)
+    chunksets_decoded: int = 0  # fetched chunksets Clay-decoded by this node
+    chunksets_decoded_on_host: int = 0  # ... of them through the numpy GF path
     # DAS sampling plane (tiny proof-carrying reads, core/extend2d.py)
     samples_served: int = 0  # shares delivered + verified (paid)
     samples_withheld: int = 0  # SP went silent — the detection signal
@@ -312,7 +316,8 @@ class RPCNode:
         transport=None,
         scheduler: HedgedScheduler | None = None,
         batch_decode: bool = True,
-        decode_matmul=None,
+        decode_matmul="auto",
+        device=None,
         cache_ttl_ms: float | None = None,
         cache_admit_bytes: int | None = None,
         admission: AdmissionSpec | None = None,
@@ -327,7 +332,14 @@ class RPCNode:
         self.transport = transport or DirectTransport(sps)
         self.scheduler = scheduler or HedgedScheduler(hedge=hedge)
         self.batch_decode = batch_decode
-        self.decode_matmul = decode_matmul  # e.g. repro.kernels.ops.gf_matmul_np
+        # the Clay-decode GF matmul: a backend name that kernels.ops
+        # resolves for `device` (a jax.Device; None = JAX's first), a
+        # callable, or None for the numpy path
+        if isinstance(decode_matmul, str):
+            from repro.kernels import ops
+
+            decode_matmul = ops.resolve_decode_matmul(decode_matmul, device)
+        self.decode_matmul = decode_matmul
         self.ledger = PaymentLedger()
         self._sp_deposit = sp_deposit
         for sp_id in sps:
@@ -697,15 +709,17 @@ class RPCNode:
             raise first_err
         if fetched:
             order = sorted(fetched)
-            if self.batch_decode and len(order) > 1:
-                decoded = self.layout.code.reconstruct_data_batch(
-                    [fetched[key].shards for key in order], matmul=self.decode_matmul
+            shard_sets = [fetched[key].shards for key in order]
+            batches = [shard_sets] if self.batch_decode else [[s] for s in shard_sets]
+            decoded = [
+                dec for batch in batches
+                for dec in self.layout.code.reconstruct_data_batch(
+                    batch, matmul=self.decode_matmul
                 )
-            else:
-                decoded = [
-                    self.layout.code.reconstruct_data(fetched[key].shards)
-                    for key in order
-                ]
+            ]
+            self.stats.chunksets_decoded += len(order)
+            if self.decode_matmul is None:
+                self.stats.chunksets_decoded_on_host += len(order)
             for key, dec in zip(order, decoded):
                 out[key] = dec
                 self._cache_put(key, dec, loop.now)

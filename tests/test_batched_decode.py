@@ -70,3 +70,27 @@ def test_rpc_batched_path_byte_identical(cluster, rng):
 
     assert batched == per_chunkset == data
     rpc.batch_decode = True
+
+
+@pytest.mark.parametrize("batch_decode", [True, False])
+def test_rpc_single_chunkset_decodes_through_node_matmul(cluster, rng, batch_decode):
+    """A one-chunkset miss goes through the node's GF matmul like a batch."""
+    from repro.core import gf
+
+    contract, sps, rpc, client = cluster
+    shapes = []
+
+    def matmul(a, b):
+        shapes.append(b.shape)
+        return gf.matmul_np(a, b)
+
+    rpc.decode_matmul = matmul
+    rpc.batch_decode = batch_decode
+    data = rng.integers(0, 256, 50_000, dtype=np.uint8).tobytes()
+    meta = client.put(data)
+    assert meta.num_chunksets == 1
+    sps[meta.placement[(0, 0)]].crash()
+    assert rpc.read_blob(meta.blob_id) == data
+    assert shapes  # the solve ran through the node's matmul
+    assert rpc.stats.chunksets_decoded == 1
+    assert rpc.stats.chunksets_decoded_on_host == 0

@@ -235,8 +235,9 @@ def test_run_sim_credits_sps_through_settled_channels():
 def test_decode_matmul_config_resolution():
     import jax
 
-    from repro.configs.shelby import CONFIG, resolve_decode_matmul
+    from repro.configs.shelby import CONFIG
     from repro.kernels import ops
+    from repro.kernels.ops import resolve_decode_matmul
 
     assert resolve_decode_matmul("numpy") is None
     assert resolve_decode_matmul("pallas") is ops.gf_matmul_np

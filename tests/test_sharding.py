@@ -50,7 +50,8 @@ _DISTRIBUTED_DRIVER = textwrap.dedent("""
     from repro.sharding import AxisCtx, TRAIN_RULES, DECODE_RULES, init_params, tree_shardings
     import dataclasses
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     rng = np.random.default_rng(0)
 
     # --- MoE: shard_map EP vs pure-local path (no-drop capacity) ---
