@@ -67,26 +67,6 @@ def tpu_devices(chips: int):
     return devices
 
 
-class CompileLog:
-    """Counts XLA compiles (cache loads included) and persistent-cache hits."""
-
-    def __init__(self):
-        import jax
-
-        self.compiles, self.seconds, self.cache_hits = 0, 0.0, 0
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
-
-    def _duration(self, event: str, secs: float, **_):
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.compiles += 1
-            self.seconds += secs
-
-    def _event(self, event: str, **_):
-        if event == "/jax/compilation_cache/cache_hits":
-            self.cache_hits += 1
-
-
 def kernel_parity(device, rng) -> None:
     """gf_matmul on the chip == numpy GF(2^8) at the decode shapes."""
     import numpy as np
@@ -168,12 +148,13 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     if not (SRC / "repro").is_dir():
         fail(f"the repro package is not at {SRC}: run from a checkout of the repo")
-    sys.path.insert(0, str(SRC))
+    sys.path[:0] = [str(SRC), str(SRC.parent)]  # the program; the harness's CompileLog
 
     import jax
     import numpy as np
 
     devices = tpu_devices(args.chips)
+    from bench.run import CompileLog
     from repro.kernels import ops
 
     cache_dir = ops.enable_compile_cache()

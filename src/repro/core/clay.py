@@ -40,6 +40,7 @@ import numpy as np
 
 from repro.core import gf
 from repro.core.rs import MDSCode
+from repro.spans import span
 
 GAMMA = 2  # gamma^2 != 1  ->  1 + gamma^2 = 5 != 0 in GF(256)
 _THETA = int(gf.inv(np.uint8(1 ^ gf.pow_(GAMMA, 2))))  # inv(1 + g^2)
@@ -200,56 +201,60 @@ class ClayCode:
 
         for score in sorted(groups):
             zs = groups[score]
+            # one span per step of each IS group, never per plane
             # 1) uncoupled values of all KNOWN nodes in these planes
-            for z in zs:
-                zi = self.plane_index[z]
-                for f in range(self.N):
-                    if f in unknown_flats:
-                        continue
-                    x, y = self._xy(f)
-                    p = self._partner(x, y, z)
-                    if p is None:
-                        u[f, zi] = c[f, zi]
-                    else:
-                        px, py, pz = p
-                        pf = self._flat(px, py)
-                        # partner C is known: either a known node, or an
-                        # unknown node whose plane has IS score-1 (already
-                        # computed in a previous group).
-                        u[f, zi] = self._u_from_pair(
-                            c[f, zi], c[pf, self.plane_index[pz]], self._pair_order(x, px)
-                        )
-                    have_u[f, zi] = True
+            with span("shelby.clay.uncouple"):
+                for z in zs:
+                    zi = self.plane_index[z]
+                    for f in range(self.N):
+                        if f in unknown_flats:
+                            continue
+                        x, y = self._xy(f)
+                        p = self._partner(x, y, z)
+                        if p is None:
+                            u[f, zi] = c[f, zi]
+                        else:
+                            px, py, pz = p
+                            pf = self._flat(px, py)
+                            # partner C is known: either a known node, or an
+                            # unknown node whose plane has IS score-1 (already
+                            # computed in a previous group).
+                            u[f, zi] = self._u_from_pair(
+                                c[f, zi], c[pf, self.plane_index[pz]], self._pair_order(x, px)
+                            )
+                        have_u[f, zi] = True
             # 2) per plane, solve the base code for unknown U
             #    (batch all planes of the group through one GF matmul)
-            zis = [self.plane_index[z] for z in zs]
-            kn = u[list(known_used)][:, zis]  # (K', G, w)
-            kn2 = kn.reshape(len(known_used), -1)
-            rec = matmul(r_mat, kn2).reshape(len(unknown_flats), len(zis), -1)
-            for row, f in enumerate(sorted(unknown_flats)):
-                for gi, zi in enumerate(zis):
-                    u[f, zi] = rec[row, gi]
-                    have_u[f, zi] = True
+            with span("shelby.clay.solve"):
+                zis = [self.plane_index[z] for z in zs]
+                kn = u[list(known_used)][:, zis]  # (K', G, w)
+                kn2 = kn.reshape(len(known_used), -1)
+                rec = matmul(r_mat, kn2).reshape(len(unknown_flats), len(zis), -1)
+                for row, f in enumerate(sorted(unknown_flats)):
+                    for gi, zi in enumerate(zis):
+                        u[f, zi] = rec[row, gi]
+                        have_u[f, zi] = True
             # 3) convert unknown nodes' U -> C
-            for z in zs:
-                zi = self.plane_index[z]
-                for f in sorted(unknown_flats):
-                    x, y = self._xy(f)
-                    p = self._partner(x, y, z)
-                    if p is None:
-                        c[f, zi] = u[f, zi]
-                        continue
-                    px, py, pz = p
-                    pf = self._flat(px, py)
-                    if pf in unknown_flats:
-                        # partner plane is in the same IS group: use both U's
-                        c[f, zi] = self._c_from_pair_u(
-                            u[f, zi], u[pf, self.plane_index[pz]], self._pair_order(x, px)
-                        )
-                    else:
-                        c[f, zi] = self._c_from_own_u_and_partner_c(
-                            u[f, zi], c[pf, self.plane_index[pz]]
-                        )
+            with span("shelby.clay.couple"):
+                for z in zs:
+                    zi = self.plane_index[z]
+                    for f in sorted(unknown_flats):
+                        x, y = self._xy(f)
+                        p = self._partner(x, y, z)
+                        if p is None:
+                            c[f, zi] = u[f, zi]
+                            continue
+                        px, py, pz = p
+                        pf = self._flat(px, py)
+                        if pf in unknown_flats:
+                            # partner plane is in the same IS group: use both U's
+                            c[f, zi] = self._c_from_pair_u(
+                                u[f, zi], u[pf, self.plane_index[pz]], self._pair_order(x, px)
+                            )
+                        else:
+                            c[f, zi] = self._c_from_own_u_and_partner_c(
+                                u[f, zi], c[pf, self.plane_index[pz]]
+                            )
         return c
 
     # -- public API -------------------------------------------------------------
@@ -258,13 +263,14 @@ class ClayCode:
 
     def encode(self, data: np.ndarray) -> np.ndarray:
         """data: (k, alpha, w) -> full codeword (n, alpha, w)."""
-        data = np.asarray(data, dtype=np.uint8)
-        assert data.shape[:2] == (self.k, self.alpha), data.shape
-        c = self._blank(data.shape[2])
-        c[: self.k] = data
-        unknown = frozenset(self.real_to_flat[self.k :])
-        c = self._solve(c, unknown)
-        return c[list(self.real_to_flat)]
+        with span("shelby.clay.encode"):
+            data = np.asarray(data, dtype=np.uint8)
+            assert data.shape[:2] == (self.k, self.alpha), data.shape
+            c = self._blank(data.shape[2])
+            c[: self.k] = data
+            unknown = frozenset(self.real_to_flat[self.k :])
+            c = self._solve(c, unknown)
+            return c[list(self.real_to_flat)]
 
     def decode(self, shards: dict[int, np.ndarray]) -> np.ndarray:
         """Reconstruct all n chunks from any >= k of them (MDS property)."""
@@ -299,27 +305,28 @@ class ClayCode:
         """
         if not shard_sets:
             return []
-        out: list[np.ndarray | None] = [None] * len(shard_sets)
-        groups: dict[tuple[int, ...], list[int]] = {}
-        for i, shards in enumerate(shard_sets):
-            if len(shards) < self.k:
-                raise ValueError(f"need >= k={self.k} shards, got {len(shards)}")
-            erased = tuple(
-                self.real_to_flat[r] for r in range(self.n) if r not in shards
-            )
-            groups.setdefault(erased, []).append(i)
-        for erased, idxs in groups.items():
-            w = next(iter(shard_sets[idxs[0]].values())).shape[-1]
-            c = np.zeros((self.N, self.alpha, w * len(idxs)), dtype=np.uint8)
-            for b, i in enumerate(idxs):
-                for real, shard in shard_sets[i].items():
-                    assert shard.shape == (self.alpha, w), shard.shape
-                    c[self.real_to_flat[real], :, b * w : (b + 1) * w] = shard
-            c = self._solve(c, frozenset(erased), matmul=matmul)
-            full = c[list(self.real_to_flat)]
-            for b, i in enumerate(idxs):
-                out[i] = np.ascontiguousarray(full[:, :, b * w : (b + 1) * w])
-        return out
+        with span("shelby.clay.decode"):
+            out: list[np.ndarray | None] = [None] * len(shard_sets)
+            groups: dict[tuple[int, ...], list[int]] = {}
+            for i, shards in enumerate(shard_sets):
+                if len(shards) < self.k:
+                    raise ValueError(f"need >= k={self.k} shards, got {len(shards)}")
+                erased = tuple(
+                    self.real_to_flat[r] for r in range(self.n) if r not in shards
+                )
+                groups.setdefault(erased, []).append(i)
+            for erased, idxs in groups.items():
+                w = next(iter(shard_sets[idxs[0]].values())).shape[-1]
+                c = np.zeros((self.N, self.alpha, w * len(idxs)), dtype=np.uint8)
+                for b, i in enumerate(idxs):
+                    for real, shard in shard_sets[i].items():
+                        assert shard.shape == (self.alpha, w), shard.shape
+                        c[self.real_to_flat[real], :, b * w : (b + 1) * w] = shard
+                c = self._solve(c, frozenset(erased), matmul=matmul)
+                full = c[list(self.real_to_flat)]
+                for b, i in enumerate(idxs):
+                    out[i] = np.ascontiguousarray(full[:, :, b * w : (b + 1) * w])
+            return out
 
     def reconstruct_data_batch(
         self, shard_sets: list[dict[int, np.ndarray]], *, matmul=None

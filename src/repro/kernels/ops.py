@@ -17,6 +17,7 @@ import numpy as np
 from repro.kernels import gf_matmul as _gf
 from repro.kernels import ref as _ref
 from repro.kernels import sample_hash as _sh
+from repro.spans import span
 
 # fixed, so that every run from this checkout finds what earlier runs cached
 _CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
@@ -61,9 +62,13 @@ def gf_matmul(a, b, *, device: jax.Device | None = None, block_n: int | None = N
 
 
 def gf_matmul_np(a: np.ndarray, b: np.ndarray, *, device: jax.Device | None = None) -> np.ndarray:
-    """numpy-in/numpy-out convenience for the storage data path."""
-    return np.asarray(gf_matmul(np.asarray(a, np.uint8), np.asarray(b, np.uint8),
-                                device=device))
+    """numpy-in/numpy-out convenience for the storage data path.
+
+    Its span covers the transfer to the device, the dispatch, the wait and
+    the copy back to the host."""
+    with span("shelby.gf.call"):
+        return np.asarray(gf_matmul(np.asarray(a, np.uint8), np.asarray(b, np.uint8),
+                                    device=device))
 
 
 def gf_traffic() -> dict[jax.Device, tuple[int, int]]:

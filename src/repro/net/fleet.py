@@ -28,6 +28,7 @@ import numpy as np
 
 from repro.net.backbone import Backbone
 from repro.net.events import EventLoop, Join, Sleep
+from repro.spans import span
 
 if TYPE_CHECKING:  # avoid a cycle: storage.rpc imports repro.net.scheduler
     from repro.storage.rpc import RPCNode
@@ -325,10 +326,11 @@ class RPCFleet:
         for (blob_id, offset, length), items in zip(ranges, per_range_items):
             meta = contract.blobs[blob_id]
             first = items[0][1]
-            data = lay.extract_range(
-                [decoded[key] for key in items], first, offset, length,
-                meta.size_bytes,
-            )
+            with span("shelby.range.extract"):
+                data = lay.extract_range(
+                    [decoded[key] for key in items], first, offset, length,
+                    meta.size_bytes,
+                )
             by_node_count: dict[str, int] = {}
             retried_nodes: dict[str, int] = {}
             latency, hits, hedges, wasted, coalesced = 0.0, 0, 0, 0, 0

@@ -63,6 +63,7 @@ from repro.net.events import (
     Transfer,
 )
 from repro.net.scheduler import FetchResult, HedgedScheduler
+from repro.spans import span
 from repro.storage.blob import BlobLayout
 from repro.storage.sp import StorageProvider
 
@@ -375,7 +376,8 @@ class RPCNode:
             assert coded.shape[0] == lay.n
             for ck in range(lay.n):
                 root_expected = meta.chunk_roots[(cs, ck)]
-                commit, _ = cm.commit_chunk(coded[ck])
+                with span("shelby.rpc.verify"):
+                    commit, _ = cm.commit_chunk(coded[ck])
                 if commit.root != root_expected:
                     raise ValueError(f"commitment mismatch for chunk ({cs},{ck})")
                 sp_id = meta.placement[(cs, ck)]
@@ -456,12 +458,13 @@ class RPCNode:
             return data
 
         def verify(ck: int, data) -> bool:
-            commit, _ = cm.commit_chunk(data)
-            if commit.root != meta.chunk_roots[(chunkset, ck)]:
-                self.stats.chunks_bad += 1  # §2.3: tampering detected
-                return False
-            self._pay(meta.placement[(chunkset, ck)])  # pay on delivery
-            return True
+            with span("shelby.rpc.verify"):
+                commit, _ = cm.commit_chunk(data)
+                if commit.root != meta.chunk_roots[(chunkset, ck)]:
+                    self.stats.chunks_bad += 1  # §2.3: tampering detected
+                    return False
+                self._pay(meta.placement[(chunkset, ck)])  # pay on delivery
+                return True
 
         result = yield from self.scheduler.fetch_task(
             loop, lay.k, candidates, issue_task, verify, label=label,

@@ -33,6 +33,7 @@ from repro.core import commitments as cm
 from repro.core.contract import BlobMetadata, ShelbyContract
 from repro.core.payments import ChannelError, MicropaymentChannel
 from repro.net.fleet import CacheAffinityPolicy, RPCFleet
+from repro.spans import span
 from repro.storage.blob import BlobLayout
 from repro.storage.rpc import RPCNode
 
@@ -237,10 +238,11 @@ class ShelbySession:
         """Batched reads: (blob_id, offset, length|None) triples, all routed
         across the fleet in ONE pass — nodes batch-decode across requests."""
         self._settle_check()
-        served = self._fleet.serve_ranges(
-            self._resolve(requests), client=client, t_ms=t_ms
-        )
-        return [self._receipt_for(sr) for sr in served]
+        with span("shelby.session.read"):
+            served = self._fleet.serve_ranges(
+                self._resolve(requests), client=client, t_ms=t_ms
+            )
+            return [self._receipt_for(sr) for sr in served]
 
     def replay(self, requests, *, background=None, trace: bool = False,
                engine: str | None = None):
@@ -742,12 +744,13 @@ class ShelbyClient:
             coded = lay.code.encode(plain)
             encoded.append(coded)
             roots = []
-            for ck in range(lay.n):
-                commit, _ = cm.commit_chunk(coded[ck])
-                chunk_roots[(cs, ck)] = commit.root
-                nsamples[(cs, ck)] = commit.num_samples
-                roots.append(commit.root)
-            cs_root, _ = cm.commit_roots(roots)
+            with span("shelby.sdk.commit"):
+                for ck in range(lay.n):
+                    commit, _ = cm.commit_chunk(coded[ck])
+                    chunk_roots[(cs, ck)] = commit.root
+                    nsamples[(cs, ck)] = commit.num_samples
+                    roots.append(commit.root)
+                cs_root, _ = cm.commit_roots(roots)
             cs_roots.append(cs_root)
         blob_root, _ = cm.commit_roots(cs_roots)
         return PreparedBlob(
@@ -761,30 +764,32 @@ class ShelbyClient:
 
     # -- write (§2.2) ---------------------------------------------------------------
     def put(self, data: bytes, payment: float = 1.0, epochs: int = 10) -> BlobMetadata:
-        prep = self.prepare(data)
-        meta = self.contract.begin_write(
-            owner="client",
-            size_bytes=prep.size_bytes,
-            n=self.layout.n,
-            k=self.layout.k,
-            blob_root=prep.blob_root,
-            chunkset_roots=prep.chunkset_roots,
-            chunk_roots=prep.chunk_roots,
-            chunk_num_samples=prep.chunk_num_samples,
-            payment=payment,
-            epochs=epochs,
-        )
-        self.fleet.primary.write_blob(meta, prep.encoded_chunksets)
-        if self.das is not None and self.das.extension:
-            # DAS plane: extend the blob into its 2k x 2k share square and
-            # disperse it alongside the chunksets (see storage/das.py)
-            from repro.storage.das import extend_and_disperse
-
-            extend_and_disperse(
-                self.contract, self.fleet.primary.sps, meta.blob_id, data,
-                self.das, matmul=self.fleet.primary.decode_matmul,
+        with span("shelby.client.put", bytes=len(data)):
+            prep = self.prepare(data)
+            meta = self.contract.begin_write(
+                owner="client",
+                size_bytes=prep.size_bytes,
+                n=self.layout.n,
+                k=self.layout.k,
+                blob_root=prep.blob_root,
+                chunkset_roots=prep.chunkset_roots,
+                chunk_roots=prep.chunk_roots,
+                chunk_num_samples=prep.chunk_num_samples,
+                payment=payment,
+                epochs=epochs,
             )
-        return meta
+            self.fleet.primary.write_blob(meta, prep.encoded_chunksets)
+            if self.das is not None and self.das.extension:
+                # DAS plane: extend the blob into its 2k x 2k share square and
+                # disperse it alongside the chunksets (see storage/das.py)
+                from repro.storage.das import extend_and_disperse
+
+                with span("shelby.das.extend"):
+                    extend_and_disperse(
+                        self.contract, self.fleet.primary.sps, meta.blob_id, data,
+                        self.das, matmul=self.fleet.primary.decode_matmul,
+                    )
+            return meta
 
     # -- reads (§2.2): pay-on-delivery via the implicit session ---------------------
     def read(

@@ -24,6 +24,16 @@ def test_gf_matmul_shapes(m, k, n):
     np.testing.assert_array_equal(out, gf.matmul_np(a, b))
 
 
+def test_gf_matmul_lowers_to_a_module_named_jit_gf_matmul():
+    """The benchmark finds the kernel's device time in a trace by the jitted
+    program's name (``gf_matmul_roofline``): a rename would empty it."""
+    from repro.kernels import gf_matmul as _gf
+
+    a, b = jnp.zeros((2, 4), jnp.uint8), jnp.zeros((4, 2048), jnp.uint8)
+    text = _gf.gf_matmul.lower(a, b, interpret=True).as_text()
+    assert text.startswith("module @jit_gf_matmul ")
+
+
 @pytest.mark.parametrize("block_n", [8, 128, 2048])
 def test_gf_matmul_block_sizes(block_n):
     rng = np.random.default_rng(block_n)
