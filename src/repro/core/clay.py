@@ -43,13 +43,61 @@ from repro.core.rs import MDSCode
 from repro.spans import span
 
 GAMMA = 2  # gamma^2 != 1  ->  1 + gamma^2 = 5 != 0 in GF(256)
-_THETA = int(gf.inv(np.uint8(1 ^ gf.pow_(GAMMA, 2))))  # inv(1 + g^2)
 _ONE_PLUS_G2 = 1 ^ gf.pow_(GAMMA, 2)
+_THETA = int(gf.inv(np.uint8(_ONE_PLUS_G2)))  # inv(1 + g^2)
 _INV_GAMMA = int(gf.inv(np.uint8(GAMMA)))
 
 
 def _ceil_div(a: int, b: int) -> int:
     return -(-a // b)
+
+
+def _word(w: int) -> np.dtype:
+    """The widest unsigned word that packs a row of w bytes exactly."""
+    return next(np.dtype(f"u{s}") for s in (8, 4, 2, 1) if w % s == 0)
+
+
+def _times_gamma(a: np.ndarray) -> np.ndarray:
+    """GAMMA * a, in place, on GF(2^8) bytes packed into unsigned words:
+    GAMMA = 2 is one xtime per byte lane, ``((a & 0x7f) << 1) ^ ((a >> 7 & 1) * 0x1d)``."""
+    lanes = a.dtype.itemsize
+    carry = a >> 7
+    carry &= int.from_bytes(b"\x01" * lanes, "little")
+    carry *= gf.POLY & 0xFF
+    a &= int.from_bytes(b"\x7f" * lanes, "little")
+    a <<= 1
+    a ^= carry
+    return a
+
+
+def _idx(values) -> np.ndarray:
+    return np.asarray(values, dtype=np.intp)
+
+
+@dataclasses.dataclass(frozen=True)
+class _GroupPlan:
+    """Index arrays for one IS group of `ClayCode._solve`.
+
+    A row is ``flat * alpha + plane`` of the codeword viewed as
+    ``(N * alpha, w)``; an out position is a row of the group's matmul
+    output, ``(unknown node, plane)`` node-major.
+    """
+
+    planes: np.ndarray  # plane indices, ascending
+    known_rows: np.ndarray  # known vertices, node-major: the matmul operand's rows
+    known_partner: np.ndarray  # each one's partner row (a diagonal vertex: its own)
+    known_diag: np.ndarray  # positions in known_rows of the diagonal vertices
+    unknown_rows: np.ndarray  # unknown vertices: partner known | partner unknown | diagonal
+    unknown_out: np.ndarray  # each one's out position
+    partner_known: np.ndarray  # rows of the known partners of the first block
+    partner_unknown: np.ndarray  # out positions of the unknown partners of the second
+
+
+@dataclasses.dataclass(frozen=True)
+class _Plan:
+    r_theta: np.ndarray  # th * R: the groups' solver, taking V to U_unknown
+    known: tuple[int, ...]  # flats of the matmul operand's node rows
+    groups: tuple[_GroupPlan, ...]  # ascending IS score
 
 
 @dataclasses.dataclass(frozen=True)
@@ -143,18 +191,6 @@ class ClayCode:
             return gf.mul(_THETA, c_self ^ gf.mul(GAMMA, c_partner))
         return gf.mul(_THETA, gf.mul(GAMMA, c_partner) ^ c_self)
 
-    @staticmethod
-    def _c_from_pair_u(u_self, u_partner, self_is_a: bool):
-        """Coupled value of `self` from both uncoupled values."""
-        if self_is_a:
-            return u_self ^ gf.mul(GAMMA, u_partner)
-        return gf.mul(GAMMA, u_partner) ^ u_self
-
-    @staticmethod
-    def _c_from_own_u_and_partner_c(u_self, c_partner):
-        """C_self = (1+g^2)*U_self + g*C_partner (both orderings)."""
-        return gf.mul(_ONE_PLUS_G2, u_self) ^ gf.mul(GAMMA, c_partner)
-
     # -- the generic plane-schedule engine -------------------------------------
     def _is_score(self, z: tuple[int, ...], unknown: frozenset[int]) -> int:
         return sum(1 for y in range(self.t) if self._flat(z[y], y) in unknown)
@@ -170,91 +206,121 @@ class ClayCode:
         r = gf.matmul_np(gf.mat_inv(he), hk)
         return r, known
 
+    @functools.lru_cache(maxsize=64)
+    def _plan(self, unknown: tuple[int, ...]) -> _Plan:
+        """The index plan `_solve` runs for one erasure pattern (sorted flats).
+
+        Built once per pattern; ``ClayCode._plan.cache_info()`` counts builds
+        (misses) against uses (hits).
+        """
+        unknown_set = frozenset(unknown)
+        r_mat, known = self._decode_mats(unknown)
+        alpha = self.alpha
+        by_score: dict[int, list[int]] = {}
+        for z in self.planes:
+            by_score.setdefault(self._is_score(z, unknown_set), []).append(self.plane_index[z])
+
+        def row(f: int, zi: int) -> int:
+            return f * alpha + zi
+
+        def partner(f: int, zi: int) -> tuple[int, int] | None:
+            """(flat, plane) of the vertex paired with (f, zi); None on the diagonal."""
+            p = self._partner(*self._xy(f), self.planes[zi])
+            return None if p is None else (self._flat(p[0], p[1]), self.plane_index[p[2]])
+
+        groups = []
+        for score in sorted(by_score):
+            zis = by_score[score]
+            # known vertices, node-major: the rows of the matmul's operand
+            known_rows, known_partner, known_diag = [], [], []
+            for f in known:
+                for zi in zis:
+                    p = partner(f, zi)
+                    if p is None:
+                        known_diag.append(len(known_rows))
+                    known_rows.append(row(f, zi))
+                    known_partner.append(row(f, zi) if p is None else row(*p))
+            # unknown vertices: (row, out position, the partner's row or out position)
+            out_pos = {v: i for i, v in enumerate(itertools.product(unknown, zis))}
+            with_known, with_unknown, diag = [], [], []
+            for (f, zi), i in out_pos.items():
+                p = partner(f, zi)
+                if p is None:
+                    diag.append((row(f, zi), i, -1))
+                elif p[0] in unknown_set:
+                    # the partner's plane has the same IS score: it is in this group
+                    with_unknown.append((row(f, zi), i, out_pos[p]))
+                else:
+                    with_known.append((row(f, zi), i, row(*p)))
+            ordered = with_known + with_unknown + diag
+            groups.append(_GroupPlan(
+                planes=_idx(zis),
+                known_rows=_idx(known_rows),
+                known_partner=_idx(known_partner),
+                known_diag=_idx(known_diag),
+                unknown_rows=_idx([r for r, _, _ in ordered]),
+                unknown_out=_idx([i for _, i, _ in ordered]),
+                partner_known=_idx([s for _, _, s in with_known]),
+                partner_unknown=_idx([s for _, _, s in with_unknown]),
+            ))
+        return _Plan(r_theta=gf.mul(_THETA, r_mat), known=known, groups=tuple(groups))
+
     def _solve(
         self, c: np.ndarray, unknown_flats: frozenset[int], matmul=None
     ) -> np.ndarray:
         """Fill in coupled values of `unknown_flats` given all other nodes.
 
         c: (N, alpha, w) uint8 with known nodes' coupled values populated
-        (virtual nodes are zero).  Returns c with unknowns filled.
+        (virtual nodes are zero).  Fills in the unknowns in place and returns
+        c (a C-contiguous copy, filled in, where c is not C-contiguous).
         Precondition: len(unknown_flats) <= m.
 
         `matmul` swaps the GF backend for the per-group linear solves
         ((M,K) x (K,N) -> (M,N) over GF(2^8)); defaults to the numpy
         table path, and accepts `repro.kernels.ops.gf_matmul_np` to route
         the wide payload product through the Pallas kernel.
+
+        Each IS group of planes (ascending score) is three whole-array passes
+        over the rows of `_plan`, with the pairwise transform rewritten so
+        that only GAMMA multiplies (one xtime) are left on the host:
+
+        1. uncouple: ``V = C + g*C_partner``, or ``(1+g^2)*C`` on the
+           diagonal, so that ``U = th*V`` for every known vertex.
+        2. solve: ``U_unknown = (th*R) @ V``, one matmul for the group.
+        3. couple: ``C = U`` on the diagonal, ``U + g*U_partner`` where the
+           partner is unknown (its plane is in the same group), and
+           ``U + g*(g*U + C_partner) = (1+g^2)*U + g*C_partner`` where it is
+           known.
         """
         matmul = matmul or gf.matmul_np
         assert len(unknown_flats) <= self.m, "more erasures than parities"
         if not unknown_flats:
             return c
-        q, t, alpha = self.q, self.t, self.alpha
-        c = c.copy()
-        u = np.zeros_like(c)  # uncoupled values, filled lazily
-        have_u = np.zeros((self.N, alpha), dtype=bool)
-
-        r_mat, known_used = self._decode_mats(tuple(sorted(unknown_flats)))
-        # group planes by intersection score, ascending
-        groups: dict[int, list[tuple[int, ...]]] = {}
-        for z in self.planes:
-            groups.setdefault(self._is_score(z, unknown_flats), []).append(z)
-
-        for score in sorted(groups):
-            zs = groups[score]
+        unknown = tuple(sorted(unknown_flats))
+        plan = self._plan(unknown)
+        w = c.shape[-1]
+        word = _word(w)
+        c = np.ascontiguousarray(c)
+        rows = c.reshape(self.N * self.alpha, w).view(word)  # writes land in c
+        for g in plan.groups:
             # one span per step of each IS group, never per plane
-            # 1) uncoupled values of all KNOWN nodes in these planes
             with span("shelby.clay.uncouple"):
-                for z in zs:
-                    zi = self.plane_index[z]
-                    for f in range(self.N):
-                        if f in unknown_flats:
-                            continue
-                        x, y = self._xy(f)
-                        p = self._partner(x, y, z)
-                        if p is None:
-                            u[f, zi] = c[f, zi]
-                        else:
-                            px, py, pz = p
-                            pf = self._flat(px, py)
-                            # partner C is known: either a known node, or an
-                            # unknown node whose plane has IS score-1 (already
-                            # computed in a previous group).
-                            u[f, zi] = self._u_from_pair(
-                                c[f, zi], c[pf, self.plane_index[pz]], self._pair_order(x, px)
-                            )
-                        have_u[f, zi] = True
-            # 2) per plane, solve the base code for unknown U
-            #    (batch all planes of the group through one GF matmul)
+                v = np.take(rows, g.known_rows, axis=0)
+                p = np.take(rows, g.known_partner, axis=0)
+                p[g.known_diag] = _times_gamma(p[g.known_diag])
+                v ^= _times_gamma(p)
             with span("shelby.clay.solve"):
-                zis = [self.plane_index[z] for z in zs]
-                kn = u[list(known_used)][:, zis]  # (K', G, w)
-                kn2 = kn.reshape(len(known_used), -1)
-                rec = matmul(r_mat, kn2).reshape(len(unknown_flats), len(zis), -1)
-                for row, f in enumerate(sorted(unknown_flats)):
-                    for gi, zi in enumerate(zis):
-                        u[f, zi] = rec[row, gi]
-                        have_u[f, zi] = True
-            # 3) convert unknown nodes' U -> C
+                rec = matmul(plan.r_theta, v.view(np.uint8).reshape(len(plan.known), -1))
+                u = np.ascontiguousarray(rec, np.uint8).view(word).reshape(len(g.unknown_out), -1)
             with span("shelby.clay.couple"):
-                for z in zs:
-                    zi = self.plane_index[z]
-                    for f in sorted(unknown_flats):
-                        x, y = self._xy(f)
-                        p = self._partner(x, y, z)
-                        if p is None:
-                            c[f, zi] = u[f, zi]
-                            continue
-                        px, py, pz = p
-                        pf = self._flat(px, py)
-                        if pf in unknown_flats:
-                            # partner plane is in the same IS group: use both U's
-                            c[f, zi] = self._c_from_pair_u(
-                                u[f, zi], u[pf, self.plane_index[pz]], self._pair_order(x, px)
-                            )
-                        else:
-                            c[f, zi] = self._c_from_own_u_and_partner_c(
-                                u[f, zi], c[pf, self.plane_index[pz]]
-                            )
+                out = np.take(u, g.unknown_out, axis=0)
+                n_known, n_unknown = len(g.partner_known), len(g.partner_unknown)
+                mixed = _times_gamma(out[:n_known].copy())
+                mixed ^= np.take(rows, g.partner_known, axis=0)
+                out[:n_known] ^= _times_gamma(mixed)
+                partners = np.take(u, g.partner_unknown, axis=0)
+                out[n_known : n_known + n_unknown] ^= _times_gamma(partners)
+                rows[g.unknown_rows] = out
         return c
 
     # -- public API -------------------------------------------------------------
