@@ -20,8 +20,9 @@ inside the DAS tree, so a sampler holding only ``das_root`` verifies a
 single share in O(log side) hashes — the proof-carrying tiny read.
 
 The GF data path is the same pluggable matmul the Clay decode uses:
-pure numpy (`gf.matmul_np`) or the Pallas ``gf_matmul`` kernel via
-``repro.kernels.ops.gf_matmul_np``.  :meth:`Extend2D.extend_batch`
+pure numpy (`gf.matmul_np`) or a Pallas kernel via
+``repro.kernels.ops.gf_matmul_np``, which gives a (k, k) parity matrix of
+256 or more coefficients to the bit-matrix kernel.  :meth:`Extend2D.extend_batch`
 deliberately concatenates MANY squares along the byte axis so thousands
 of per-share GF ops become ONE small-and-wide (k x k) @ (k x B*k*S)
 kernel call — the opposite kernel regime from the few-and-large

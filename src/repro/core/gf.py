@@ -112,11 +112,21 @@ def solve(a: np.ndarray, b: np.ndarray) -> np.ndarray:
     return matmul_np(mat_inv(a), b)
 
 
+def field_points(count: int) -> np.ndarray:
+    """The first ``count`` points of the field in the codes' order: 1, 2, ...,
+    255 and then 0, so a code of length 256 uses every element once."""
+    if not 0 < count <= 256:
+        raise ValueError(f"GF(2^8) has 256 points, not {count}")
+    return (np.arange(1, count + 1) % 256).astype(np.uint8)
+
+
 def vandermonde(rows: int, cols: int, points: np.ndarray | None = None) -> np.ndarray:
-    """Vandermonde matrix V[i,j] = points[j]^i; any `rows` distinct columns of a
-    row-prefix are invertible, so it serves as an MDS parity-check."""
+    """Vandermonde matrix V[i,j] = points[j]^i (0^0 = 1) on distinct points,
+    by default :func:`field_points`; any `rows` columns of a row-prefix form a
+    square Vandermonde matrix, which is invertible, so it serves as an MDS
+    parity-check."""
     if points is None:
-        points = np.arange(1, cols + 1, dtype=np.uint8)  # distinct nonzero
+        points = field_points(cols)
     points = np.asarray(points, dtype=np.uint8)
     assert len(points) == cols and len(np.unique(points)) == cols
     v = np.zeros((rows, cols), dtype=np.uint8)

@@ -7,8 +7,9 @@ coupled-layer (Clay) construction in ``clay.py``.
 Code definition: an ``[n, k]`` code with ``m = n - k`` parity symbols and a
 parity-check matrix ``H`` (m x n).  A vector ``c`` (length n, per byte column)
 is a codeword iff ``H @ c = 0`` over GF(2^8).  ``H`` is Vandermonde on distinct
-nonzero points, so every ``m x m`` column submatrix (of the full row set) is
-invertible -> the code is MDS: any ``k`` symbols determine the rest.
+points (``gf.field_points``: 1..255, then 0, so n may reach 256), so every
+``m x m`` column submatrix (of the full row set) is invertible -> the code is
+MDS: any ``k`` symbols determine the rest.
 
 The *data path* (multiplying a small decode/encode matrix into wide byte
 arrays) is delegated to ``repro.kernels.gf_matmul`` (Pallas) or to the pure

@@ -1,5 +1,6 @@
 """Jit'd public wrappers around the Pallas kernels, and the one place that
-decides whether GF(2^8) decode runs on the host or on the device.
+decides whether GF(2^8) decode runs on the host or on the device, and on
+which of the two GF kernels.
 
 On CPU (the test suite) the kernels execute with ``interpret=True``; on a
 TPU they compile to Mosaic.  ``repro.core``/``repro.storage`` call only
@@ -14,6 +15,7 @@ from pathlib import Path
 import jax
 import numpy as np
 
+from repro.kernels import gf_bitmatmul as _gb
 from repro.kernels import gf_matmul as _gf
 from repro.kernels import ref as _ref
 from repro.kernels import sample_hash as _sh
@@ -45,8 +47,23 @@ def enable_compile_cache() -> str:
     return jax.config.jax_compilation_cache_dir
 
 
+# From this many coefficients on, an (M, K) matrix goes to the bit-matrix
+# kernel: its (8M, 8K) operand then covers at least one 128 x 128 MXU tile,
+# while the VPU kernel's body, which unrolls 8 xtime steps per coefficient,
+# already takes seconds to trace (16 x 16).
+_BIT_MATRIX_MIN_COEFFS = 256
+
+
+def uses_bit_matrix(m: int, k: int) -> bool:
+    """Whether an (M, K) coefficient matrix runs on the bit-matrix kernel
+    (``gf_bitmatmul``, the MXU) rather than ``gf_matmul`` (the VPU): the one
+    place that chooses between them, by shape alone."""
+    return m * k >= _BIT_MATRIX_MIN_COEFFS
+
+
 def gf_matmul(a, b, *, device: jax.Device | None = None, block_n: int | None = None):
-    """GF(2^8) matmul via the Pallas kernel (interpret-mode off-TPU).
+    """GF(2^8) matmul via a Pallas kernel (interpret-mode off-TPU), the
+    kernel chosen by :func:`uses_bit_matrix`.
 
     Runs on ``device`` (JAX's first device when None) and counts the call
     and the bytes of ``b`` against it (see :func:`gf_traffic`).
@@ -54,7 +71,8 @@ def gf_matmul(a, b, *, device: jax.Device | None = None, block_n: int | None = N
     device = device or jax.devices()[0]
     a, b = jax.device_put((a, b), device)
     kwargs = {} if block_n is None else {"block_n": block_n}
-    out = _gf.gf_matmul(a, b, interpret=device.platform != "tpu", **kwargs)
+    kernel = _gb.gf_bitmatmul if uses_bit_matrix(*a.shape) else _gf.gf_matmul
+    out = kernel(a, b, interpret=device.platform != "tpu", **kwargs)
     traffic = _GF_TRAFFIC.setdefault(device, [0, 0])
     traffic[0] += 1
     traffic[1] += b.nbytes
@@ -81,8 +99,8 @@ def reset_gf_traffic() -> None:
 
 
 def gf_compilations() -> int:
-    """Distinct gf_matmul programs compiled in this process (one per shape)."""
-    return _gf.gf_matmul._cache_size()
+    """Distinct GF matmul programs compiled in this process (one per shape)."""
+    return _gf.gf_matmul._cache_size() + _gb.gf_bitmatmul._cache_size()
 
 
 def resolve_decode_matmul(choice: str = "auto", device: jax.Device | None = None):

@@ -679,6 +679,14 @@ class BlobReader:
         self.close()
 
 
+@dataclasses.dataclass
+class ClientStats:
+    """Counters of a client's writes."""
+
+    das_squares_extended: int = 0  # blobs extended into a DAS square on put
+    das_shares_placed: int = 0  # shares of those squares sent to their SPs
+
+
 class ShelbyClient:
     """Fleet-first client: writes disperse through the fleet's primary
     node; reads flow through a session (per-node channels, receipts,
@@ -703,6 +711,7 @@ class ShelbyClient:
         self.read_price_per_byte = read_price_per_byte
         self.deposit_per_node = deposit
         self.das = das
+        self.stats = ClientStats()
         self._session: ShelbySession | None = None
 
     @property
@@ -785,10 +794,12 @@ class ShelbyClient:
                 from repro.storage.das import extend_and_disperse
 
                 with span("shelby.das.extend"):
-                    extend_and_disperse(
+                    record = extend_and_disperse(
                         self.contract, self.fleet.primary.sps, meta.blob_id, data,
                         self.das, matmul=self.fleet.primary.decode_matmul,
                     )
+                self.stats.das_squares_extended += 1
+                self.stats.das_shares_placed += len(record.placement)
             return meta
 
     # -- reads (§2.2): pay-on-delivery via the implicit session ---------------------

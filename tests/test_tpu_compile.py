@@ -14,6 +14,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.kernels import gf_bitmatmul as _gb
 from repro.kernels import gf_matmul as _gf
 from repro.kernels import sample_hash as _sh
 
@@ -49,6 +50,15 @@ def _compile(fn, one_chip, *shapes_dtypes, **static):
 ])
 def test_gf_matmul_compiles_for_v5e(one_chip, m, k, n):
     compiled = _compile(_gf.gf_matmul, one_chip, ((m, k), jnp.uint8), ((k, n), jnp.uint8))
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("m,k,n", [
+    (128, 128, 65536),  # the 128 x 128 DAS square's column extension: k x (k x 512) bytes
+    (128, 128, 131072),  # its row extension: k x (2k x 512) bytes
+])
+def test_gf_bitmatmul_compiles_for_v5e(one_chip, m, k, n):
+    compiled = _compile(_gb.gf_bitmatmul, one_chip, ((m, k), jnp.uint8), ((k, n), jnp.uint8))
     assert "tpu_custom_call" in compiled.as_text()
 
 
