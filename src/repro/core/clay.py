@@ -35,6 +35,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import itertools
+from collections.abc import Callable
 
 import numpy as np
 
@@ -106,6 +107,10 @@ class ClayCode:
 
     k: int
     m: int
+    # the GF matmul of the encode's parity solve (None: numpy); not part of
+    # the code's identity, so a bound copy shares its cached plans
+    matmul: Callable[[np.ndarray, np.ndarray], np.ndarray] | None = dataclasses.field(
+        default=None, compare=False, repr=False)
 
     def __post_init__(self):
         assert self.k >= 1 and self.m >= 1
@@ -328,14 +333,18 @@ class ClayCode:
         return np.zeros((self.N, self.alpha, w), dtype=np.uint8)
 
     def encode(self, data: np.ndarray) -> np.ndarray:
-        """data: (k, alpha, w) -> full codeword (n, alpha, w)."""
+        """data: (k, alpha, w) -> full codeword (n, alpha, w).
+
+        The parity solve runs through the code's `matmul`: bound to
+        ``repro.kernels.ops.gf_matmul_np``, the (m, N - m) x (N - m, alpha * w)
+        product runs on the device.  The parity bytes are the same either way."""
         with span("shelby.clay.encode"):
             data = np.asarray(data, dtype=np.uint8)
             assert data.shape[:2] == (self.k, self.alpha), data.shape
             c = self._blank(data.shape[2])
             c[: self.k] = data
             unknown = frozenset(self.real_to_flat[self.k :])
-            c = self._solve(c, unknown)
+            c = self._solve(c, unknown, matmul=self.matmul)
             return c[list(self.real_to_flat)]
 
     def decode(self, shards: dict[int, np.ndarray]) -> np.ndarray:

@@ -30,6 +30,7 @@ import dataclasses
 import numpy as np
 
 from repro.core import commitments as cm
+from repro.core.clay import ClayCode
 from repro.core.contract import BlobMetadata, ShelbyContract
 from repro.core.payments import ChannelError, MicropaymentChannel
 from repro.net.fleet import CacheAffinityPolicy, RPCFleet
@@ -685,6 +686,8 @@ class ClientStats:
 
     das_squares_extended: int = 0  # blobs extended into a DAS square on put
     das_shares_placed: int = 0  # shares of those squares sent to their SPs
+    chunksets_encoded: int = 0  # chunksets Clay-encoded by this client
+    chunksets_encoded_on_host: int = 0  # ... of them through the numpy GF path
 
 
 class ShelbyClient:
@@ -713,6 +716,7 @@ class ShelbyClient:
         self.das = das
         self.stats = ClientStats()
         self._session: ShelbySession | None = None
+        self._code: ClayCode | None = None  # the layout's code, bound by `_encoder`
 
     @property
     def rpc(self) -> RPCNode:
@@ -745,13 +749,28 @@ class ShelbyClient:
             self.settle()
 
     # -- data preparation (Figure 2) ---------------------------------------------
+    def _encoder(self) -> ClayCode:
+        """The layout's code carrying the primary node's GF matmul as its
+        encode's solve backend (its device; numpy where it is None), bound
+        once per code and matmul."""
+        code, matmul = self.layout.code, self.fleet.primary.decode_matmul
+        if self._code is None or self._code != code or self._code.matmul is not matmul:
+            self._code = dataclasses.replace(code, matmul=matmul)
+        return self._code
+
     def prepare(self, data: bytes) -> PreparedBlob:
+        """Encode and commit ``data``, the parity solve on the primary
+        node's GF matmul."""
         lay = self.layout
+        code = self._encoder()
         chunksets = lay.partition(data)
         encoded, chunk_roots, nsamples, cs_roots = [], {}, {}, []
         for cs, plain in enumerate(chunksets):
-            coded = lay.code.encode(plain)
+            coded = code.encode(plain)
             encoded.append(coded)
+            self.stats.chunksets_encoded += 1
+            if code.matmul is None:
+                self.stats.chunksets_encoded_on_host += 1
             roots = []
             with span("shelby.sdk.commit"):
                 for ck in range(lay.n):

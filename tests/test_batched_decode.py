@@ -89,6 +89,8 @@ def test_rpc_single_chunkset_decodes_through_node_matmul(cluster, rng, batch_dec
     data = rng.integers(0, 256, 50_000, dtype=np.uint8).tobytes()
     meta = client.put(data)
     assert meta.num_chunksets == 1
+    assert shapes  # the put's encode ran through it too
+    shapes.clear()
     sps[meta.placement[(0, 0)]].crash()
     assert rpc.read_blob(meta.blob_id) == data
     assert shapes  # the solve ran through the node's matmul

@@ -1,4 +1,5 @@
 """Clay code properties: systematic, MDS (any k of n), optimal repair."""
+import dataclasses
 import itertools
 import random
 
@@ -100,6 +101,31 @@ def test_roundtrip_random_params(k, m, seed):
     erased = set(r.sample(range(code.n), m))
     shards = {i: cw[i] for i in range(code.n) if i not in erased}
     assert np.array_equal(code.decode(shards), cw)
+
+
+@pytest.mark.parametrize("k,m,backend", [(k, m, "numpy") for k, m in PARAMS]
+                         + [(4, 2, "kernel")])
+def test_encode_through_a_matmul_is_byte_equal(k, m, backend):
+    """`encode` of a code bound to a matmul gives `encode(data)`'s bytes,
+    with one call of the (m, N - m) coefficient matrix per IS group.
+    "kernel" runs the Pallas kernel (interpret mode off-TPU); wide codes
+    stay on numpy, whose interpret-mode kernel takes seconds to trace."""
+    from repro.kernels import ops
+
+    code, data, cw = _codeword(k, m, w=16)
+    inner = ops.gf_matmul_np if backend == "kernel" else gf.matmul_np
+    calls = []
+
+    def matmul(a, b):
+        calls.append((a.shape, b.shape))
+        return inner(a, b)
+
+    groups = code._plan(tuple(sorted(code.real_to_flat[k:]))).groups
+    known = code.N - m
+    np.testing.assert_array_equal(dataclasses.replace(code, matmul=matmul).encode(data), cw)
+    assert len(calls) == len(groups)
+    assert all(a == (m, known) and b[0] == known for a, b in calls), calls
+    assert sum(b[1] for _, b in calls) == code.alpha * 16
 
 
 def test_too_few_shards_raises():

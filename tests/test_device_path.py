@@ -45,6 +45,54 @@ def test_rpc_node_defaults_to_the_platform_choice(cluster):
     assert node.decode_matmul.keywords == {"device": jax.devices()[0]}
 
 
+def _logged_matmul(calls):
+    def matmul(a, b):
+        calls.append((a.shape, b.shape))
+        return gf.matmul_np(a, b)
+
+    return matmul
+
+
+def test_prepare_encodes_through_the_node_matmul(cluster, rng):
+    contract, sps, rpc, client = cluster
+    data = rng.integers(0, 256, 150_000, dtype=np.uint8).tobytes()
+    calls = []
+    rpc.decode_matmul = _logged_matmul(calls)
+    prep = client.prepare(data)
+    chunksets = len(prep.encoded_chunksets)
+    assert chunksets == 3
+    lay = client.layout
+    assert calls and all(a == (lay.m, lay.code.N - lay.m) for a, _ in calls)
+    assert client.stats.chunksets_encoded == chunksets
+    assert client.stats.chunksets_encoded_on_host == 0
+    meta = client.put(data)
+    assert client.stats.chunksets_encoded == 2 * chunksets
+    assert client.stats.chunksets_encoded_on_host == 0
+    assert client._encoder() is client._encoder()  # bound once, not per put
+    assert client.get(meta.blob_id) == data
+
+
+def test_prepare_without_a_node_matmul_encodes_on_host_identically(cluster, rng):
+    contract, sps, rpc, client = cluster
+    data = rng.integers(0, 256, 150_000, dtype=np.uint8).tobytes()
+    assert rpc.decode_matmul is None
+    on_host = client.prepare(data)
+    assert client.stats.chunksets_encoded_on_host == len(on_host.encoded_chunksets) == 3
+    assert client.stats.chunksets_encoded == 3
+    calls = []
+    rpc.decode_matmul = _logged_matmul(calls)
+    on_device = client.prepare(data)
+    assert calls  # the code was bound anew to the node's new matmul
+    for got, want in zip(on_device.encoded_chunksets, on_host.encoded_chunksets, strict=True):
+        np.testing.assert_array_equal(got, want)
+    assert (on_device.size_bytes, on_device.chunk_roots, on_device.chunk_num_samples,
+            on_device.chunkset_roots, on_device.blob_root) == (
+        on_host.size_bytes, on_host.chunk_roots, on_host.chunk_num_samples,
+        on_host.chunkset_roots, on_host.blob_root)
+    assert client.stats.chunksets_encoded_on_host == 3
+    assert client.stats.chunksets_encoded == 6
+
+
 def test_compile_cache_uses_the_env_dir_else_the_checkout(tmp_path):
     script = ("from repro.kernels import ops; import jax, jax.numpy as jnp; "
               "print(ops.enable_compile_cache()); "

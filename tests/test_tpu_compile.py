@@ -45,7 +45,8 @@ def _compile(fn, one_chip, *shapes_dtypes, **static):
 @pytest.mark.parametrize("m,k,n", [
     (2, 16, 699264),  # (10,6) Clay, 2 erasures, one IS group of one chunkset
     (6, 16, 1048464),  # 6 unknowns over a whole chunkset's planes
-    (6, 12, 1048896),  # a k-of-n read: 6 erasures, 12 known nodes
+    (6, 12, 1048896),  # a k-of-n read (6 erasures, 12 known nodes) and the (10,6) encode
+    (4, 16, 655360),  # the (20,16) encode: 4 parities from 16 known nodes, alpha=1024, w=640
     (4, 4, 4096),  # the DAS extension's (4,4) square
 ])
 def test_gf_matmul_compiles_for_v5e(one_chip, m, k, n):
