@@ -106,7 +106,7 @@ path = os.environ["BENCH_JSON"]
 with open(path) as f:
     doc = json.load(f)
 for section in ("serve_grid", "concurrent_ramp", "background", "churn", "das",
-                "tune_admission", "engine"):
+                "tune_admission"):
     assert section in doc, f"{path} missing section {section!r}"
 print(f"{path}: {', '.join(sorted(doc))} OK")
 EOF

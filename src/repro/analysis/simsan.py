@@ -214,7 +214,7 @@ def check_payment_conservation(session: Any, *, where: str = "") -> None:
 
     This is the same invariant ``ShelbySession.close()`` enforces at
     settlement — per serving node, the sum of receipt payments (read
-    receipts, DAS sample receipts, and batched background receipts) must
+    receipts and DAS sample receipts) must
     equal the channel's ``paid`` within float tolerance — hoisted out so
     ``run_sim`` can assert it at every epoch boundary under simsan.  A
     mismatch means value was created or destroyed between a read and its
@@ -223,9 +223,6 @@ def check_payment_conservation(session: Any, *, where: str = "") -> None:
     expected: dict[Any, float] = {}
     for r in getattr(session, "receipts", []):
         for rpc_id, amount in getattr(r, "payments", {}).items():
-            expected[rpc_id] = expected.get(rpc_id, 0.0) + amount
-    for rb in getattr(session, "receipt_batches", []):
-        for rpc_id, amount in getattr(rb, "paid_by_node", {}).items():
             expected[rpc_id] = expected.get(rpc_id, 0.0) + amount
 
     channels = getattr(session, "channels", {})

@@ -35,10 +35,6 @@ class ShelbyConfig:
     rpc_max_inflight_fetches: int | None = None  # live SP fetch tasks per node
     rpc_shed_deadline_ms: float | None = None  # brownout SLO on EWMA fetch ms
     # event-engine service/network model
-    # event-queue discipline: "calendar" (O(1) amortized calendar queue,
-    # the default) or "heap" (the binary-heap baseline); pop order — and
-    # therefore every determinism digest — is identical on both
-    event_engine: str = "calendar"
     sp_service_slots: int = 4  # concurrent disk reads per SP (FIFO queue beyond)
     # per-node NIC line rate wherever a Backbone is built from this config
     # (the concurrent serving bench); None = unlimited nodes
@@ -270,11 +266,6 @@ KNOB_DOCS: dict[str, str] = {
         "unit: sim ms | None; default: None (off). Brownout SLO: shed "
         "while the EWMA of observed fetch latency exceeds it. Exercised "
         "by: tune_admission sweeps; brownout tests in test_overload.py."
-    ),
-    "event_engine": (
-        "unit: calendar|heap; default: calendar. Event-queue discipline; "
-        "pop order and every determinism digest are identical on both. "
-        "Exercised by: engine scenario (fast-vs-heap digest equality)."
     ),
     "sp_service_slots": (
         "unit: slots; default: 4. Concurrent disk reads per SP; FIFO "

@@ -60,10 +60,6 @@ class ServedRange:
 class LatencyAwarePolicy:
     """Route to the node minimizing propagation + recent-latency EWMA."""
 
-    # routing depends on live fleet state (EWMA, routed counts): the cohort
-    # fast path cannot precompute it, so batches de-opt to task mode
-    static = False
-
     def pick(self, key: tuple[int, int], client: str | None, fleet: "RPCFleet") -> int:
         def est(i: int) -> tuple[float, int, int]:
             prop = 0.0
@@ -78,11 +74,8 @@ class CacheAffinityPolicy:
     """Rendezvous hashing on (blob_id, chunkset) -> stable home node.
 
     A pure function of (key, node set), so picks are memoized: a hot key
-    re-routed a million times costs one sha256 sweep, not a million — and
-    the cohort fast path can route whole batches through the same memo.
+    re-routed a million times costs one sha256 sweep, not a million.
     """
-
-    static = True  # pick depends only on (key, node set): vectorizable
 
     def __init__(self):
         self._memo: dict[tuple[int, int], int] = {}
@@ -107,8 +100,6 @@ class CacheAffinityPolicy:
 
 class PowerOfTwoPolicy:
     """Two seeded random probes, pick the less-loaded (routed count)."""
-
-    static = False  # consumes an rng stream in routing order
 
     def __init__(self, seed: int = 0):
         self._rng = np.random.default_rng(seed)

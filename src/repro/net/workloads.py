@@ -70,135 +70,6 @@ class SampleRequest:
     cache_bypass: bool = True
 
 
-@dataclasses.dataclass
-class RequestBatch:
-    """Struct-of-arrays block of read requests — the million-request form.
-
-    One frozen :class:`ReadRequest` per request costs hundreds of bytes of
-    Python object; a 1M-request storm held that way is ~0.5 GB of boxed
-    floats before the engine even starts.  A batch keeps the five columns
-    as numpy arrays (client names interned once in ``clients``), which is
-    what the cohort fast path (``repro.net.fastpath``) consumes directly —
-    ``to_requests()`` materializes the identical request list for the
-    task-per-request drivers, so the same batch replays on either path.
-    """
-
-    t_ms: np.ndarray  # float64 arrival times
-    client_idx: np.ndarray  # index into ``clients``
-    blob_id: np.ndarray  # int64
-    offset: np.ndarray  # int64
-    length: np.ndarray  # int64
-    clients: list[str]
-
-    def __len__(self) -> int:
-        return int(self.t_ms.size)
-
-    def request(self, i: int) -> ReadRequest:
-        return ReadRequest(
-            float(self.t_ms[i]), self.clients[int(self.client_idx[i])],
-            int(self.blob_id[i]), int(self.offset[i]), int(self.length[i]),
-        )
-
-    def to_requests(self) -> list[ReadRequest]:
-        """Materialize the equivalent per-request list (task-mode replay)."""
-        names = self.clients
-        return [
-            ReadRequest(t, names[c], b, off, ln)
-            for t, c, b, off, ln in zip(
-                self.t_ms.tolist(), self.client_idx.tolist(),
-                self.blob_id.tolist(), self.offset.tolist(),
-                self.length.tolist(),
-            )
-        ]
-
-
-def zipf_hotset_batch(
-    metas,
-    *,
-    clients: list[str],
-    num_requests: int = 200,
-    exponent: float = 1.1,
-    read_bytes: int = 64 * 1024,
-    interarrival_ms: float = 0.4,
-    seed: int = 0,
-    arrival: str = "fixed",
-) -> RequestBatch:
-    """Vectorized Zipf storm: every column drawn as ONE numpy array.
-
-    Same workload *shape* as :func:`zipf_hotset` (Zipf-ranked blobs behind
-    a seeded rank permutation, uniform in-blob offsets, uniform clients,
-    fixed or Poisson gaps) but each column is a single vectorized draw, so
-    generating 1M requests costs milliseconds, not seconds.  The draw
-    *order* differs from the scalar generator's interleaved stream, so the
-    two are distinct seeded workloads — existing benches keep their exact
-    request sequences, big-world ramps use this.
-    """
-    if arrival not in ("fixed", "poisson"):
-        raise ValueError(f"arrival must be fixed|poisson, got {arrival!r}")
-    n = num_requests
-    rng = np.random.default_rng(seed)
-    ranks = np.arange(1, len(metas) + 1, dtype=np.float64)
-    popularity = ranks**-exponent
-    popularity /= popularity.sum()
-    blob_order = rng.permutation(len(metas))  # which blob holds which rank
-    sizes = np.array([m.size_bytes for m in metas], dtype=np.int64)
-    blob_ids = np.array([m.blob_id for m in metas], dtype=np.int64)
-    picks = blob_order[rng.choice(len(metas), size=n, p=popularity)]
-    sz = sizes[picks]
-    length = np.minimum(read_bytes, sz)
-    offset = (rng.random(n) * (sz - length + 1)).astype(np.int64)
-    client_idx = rng.integers(0, len(clients), size=n)
-    if arrival == "poisson":
-        gaps = rng.exponential(interarrival_ms, size=n)
-        gaps[0] = 0.0
-        t = np.cumsum(gaps)
-    else:
-        t = np.arange(n, dtype=np.float64) * interarrival_ms
-    return RequestBatch(
-        t_ms=t, client_idx=client_idx, blob_id=blob_ids[picks],
-        offset=offset, length=length, clients=list(clients),
-    )
-
-
-def das_storm_batch(
-    das_records,
-    *,
-    clients: list[str],
-    num_requests: int = 200,
-    interarrival_ms: float = 0.3,
-    seed: int = 0,
-    arrival: str = "poisson",
-    cache_bypass: bool = True,
-) -> list[SampleRequest]:
-    """Vectorized DAS storm: blobs, (row, col) coordinates, clients and
-    gaps drawn as whole numpy arrays up front (cf. :func:`das_storm`, whose
-    per-request scalar draws pin the existing bench sequences)."""
-    if arrival not in ("fixed", "poisson"):
-        raise ValueError(f"arrival must be fixed|poisson, got {arrival!r}")
-    recs = list(das_records)
-    n = num_requests
-    rng = np.random.default_rng(seed)
-    ri = rng.integers(0, len(recs), size=n)
-    sides = np.array([r.side for r in recs], dtype=np.int64)[ri]
-    rows = (rng.random(n) * sides).astype(np.int64)
-    cols = (rng.random(n) * sides).astype(np.int64)
-    ci = rng.integers(0, len(clients), size=n)
-    if arrival == "poisson":
-        gaps = rng.exponential(interarrival_ms, size=n)
-        gaps[0] = 0.0
-        t = np.cumsum(gaps)
-    else:
-        t = np.arange(n, dtype=np.float64) * interarrival_ms
-    blob_ids = np.array([r.blob_id for r in recs], dtype=np.int64)[ri]
-    return [
-        SampleRequest(tt, clients[c], b, r, cc, cache_bypass=cache_bypass)
-        for tt, c, b, r, cc in zip(
-            t.tolist(), ci.tolist(), blob_ids.tolist(),
-            rows.tolist(), cols.tolist(),
-        )
-    ]
-
-
 def video_streaming(
     meta,
     *,
@@ -380,57 +251,8 @@ class RequestRecord:
 
 
 @dataclasses.dataclass
-class RecordBatch:
-    """Struct-of-arrays pool of request records (one row per request).
-
-    The fast path's counterpart to ``list[RequestRecord]``: a 1M-request
-    replay keeps nine columns instead of a million frozen dataclasses, and
-    every aggregate (shed counts, goodput, percentiles, the digest rows)
-    reduces over arrays.  Rows are in request-index order and cover EVERY
-    request of the replay — including the ones that de-opted to real
-    generator tasks, whose records are folded back in after the loop runs.
-    """
-
-    index: np.ndarray  # int64
-    t_ms: np.ndarray  # float64
-    finish_ms: np.ndarray
-    latency_ms: np.ndarray
-    nbytes: np.ndarray  # int64
-    ok: np.ndarray  # bool
-    shed: np.ndarray  # bool
-    client_idx: np.ndarray  # index into ``clients``
-    blob_id: np.ndarray  # int64
-    clients: list[str]
-    kind: str = "read"
-
-    def __len__(self) -> int:
-        return int(self.index.size)
-
-    def to_records(self) -> list[RequestRecord]:
-        names = self.clients
-        return [
-            RequestRecord(i, t, f, lat, nb, ok, names[c], b, shed, self.kind)
-            for i, t, f, lat, nb, ok, c, b, shed in zip(
-                self.index.tolist(), self.t_ms.tolist(),
-                self.finish_ms.tolist(), self.latency_ms.tolist(),
-                self.nbytes.tolist(), self.ok.tolist(),
-                self.client_idx.tolist(), self.blob_id.tolist(),
-                self.shed.tolist(),
-            )
-        ]
-
-
-@dataclasses.dataclass
 class ReplayResult:
-    """Outcome of replaying a workload through the shared event loop.
-
-    Task-mode drivers fill ``records``; the cohort fast path fills
-    ``batch`` (one :class:`RecordBatch` row per request, de-opted task
-    records folded back in) and leaves ``records`` empty.  Every aggregate
-    below reads both, so callers never care which driver produced the
-    result — including ``digest()``, whose per-request rows are formatted
-    identically from either representation.
-    """
+    """Outcome of replaying a workload through the shared event loop."""
 
     records: list[RequestRecord]
     span_ms: float  # first arrival -> last client-observed finish
@@ -438,14 +260,7 @@ class ReplayResult:
     trace: list[tuple[float, str, str]] | None = None  # loop audit trail
     # background-plane operations (audits, repairs) that shared the loop
     background: list[BackgroundRecord] = dataclasses.field(default_factory=list)
-    # pooled per-request rows from the cohort fast path (records stay empty)
-    batch: RecordBatch | None = None
-    # fast-path cohort accounting (repro.net.fastpath.CohortStats): which
-    # requests advanced vectorized, which individuated into tasks, and the
-    # per-leg (request, node) attribution payment batching consumes
-    cohort: object = None
-    # engine telemetry: events the loop processed + wall-clock spent (the
-    # fast path adds one event per vectorized request completion)
+    # engine telemetry: events the loop processed + wall-clock spent
     engine_events: int = 0
     engine_wall_s: float = 0.0
 
@@ -458,41 +273,27 @@ class ReplayResult:
 
     @property
     def num_requests(self) -> int:
-        return len(self.records) + (len(self.batch) if self.batch is not None else 0)
+        return len(self.records)
 
     @property
     def dropped(self) -> int:
         """Hard failures only; admission refusals are counted by `shed`."""
-        n = sum(1 for r in self.records if not r.ok and not r.shed)
-        if self.batch is not None:
-            n += int(np.count_nonzero(~self.batch.ok & ~self.batch.shed))
-        return n
+        return sum(1 for r in self.records if not r.ok and not r.shed)
 
     @property
     def shed(self) -> int:
         """Requests the fleet refused at admission (typed Overloaded)."""
-        n = sum(1 for r in self.records if r.shed)
-        if self.batch is not None:
-            n += int(np.count_nonzero(self.batch.shed))
-        return n
+        return sum(1 for r in self.records if r.shed)
 
     @property
     def shed_rate(self) -> float:
         total = self.num_requests
         return self.shed / total if total else 0.0
 
-    def _arrivals(self) -> np.ndarray:
-        parts = []
-        if self.records:
-            parts.append(np.array([r.t_ms for r in self.records]))
-        if self.batch is not None and len(self.batch):
-            parts.append(self.batch.t_ms)
-        return np.concatenate(parts) if parts else np.empty(0)
-
     @property
     def offered_rps(self) -> float:
         """Offered load: arrivals over the arrival window (requests/s)."""
-        t = self._arrivals()
+        t = np.array([r.t_ms for r in self.records])
         if t.size < 2:
             return 0.0
         window = float(t.max() - t.min())
@@ -504,18 +305,13 @@ class ReplayResult:
         if self.span_ms <= 0:
             return 0.0
         nbytes = sum(r.nbytes for r in self.records if r.ok)
-        if self.batch is not None:
-            nbytes += int(self.batch.nbytes[self.batch.ok].sum())
         return nbytes * 8e-3 / self.span_ms
 
     def latencies_ms(self, kind: str | None = None) -> list[float]:
-        lats = [
+        return [
             r.latency_ms for r in self.records
             if r.ok and (kind is None or r.kind == kind)
         ]
-        if self.batch is not None and (kind is None or kind == self.batch.kind):
-            lats.extend(self.batch.latency_ms[self.batch.ok].tolist())
-        return lats
 
     def percentile(self, q: float, kind: str | None = None) -> float:
         lats = self.latencies_ms(kind)
@@ -568,22 +364,6 @@ class ReplayResult:
                 f"{r.index}|{r.t_ms!r}|{r.finish_ms!r}|{r.latency_ms!r}|"
                 f"{r.nbytes}|{r.ok}|{r.client}|{r.blob_id}|{r.shed}|{r.kind}\n".encode()
             )
-        if self.batch is not None:
-            # identical row format from the pooled columns (``.tolist()``
-            # yields native float/int/bool, so every !r matches the record
-            # path byte for byte) — a fast replay and a task replay of the
-            # same schedule digest equal
-            b = self.batch
-            names, kind = b.clients, b.kind
-            for i, t, f, lat, nb, ok, c, blob, shed in zip(
-                b.index.tolist(), b.t_ms.tolist(), b.finish_ms.tolist(),
-                b.latency_ms.tolist(), b.nbytes.tolist(), b.ok.tolist(),
-                b.client_idx.tolist(), b.blob_id.tolist(), b.shed.tolist(),
-            ):
-                h.update(
-                    f"{i}|{t!r}|{f!r}|{lat!r}|{nb}|{ok}|{names[c]}|{blob}|"
-                    f"{shed}|{kind}\n".encode()
-                )
         for b in self.background:
             h.update(
                 f"bg|{b.kind}|{b.key}|{b.t_ms!r}|{b.finish_ms!r}|{b.ok}|"
@@ -756,8 +536,6 @@ def replay_open_loop(
     audit proofs and repair helper reads contend with the replay for NICs,
     trunks and SP disk slots, and their records land in
     ``ReplayResult.background`` (covered by the determinism digest)."""
-    if isinstance(requests, RequestBatch):
-        requests = requests.to_requests()
     loop = EventLoop(network=fleet.network, trace=trace, engine=engine)
     records: list[RequestRecord | None] = [None] * len(requests)
     for i, req in enumerate(requests):

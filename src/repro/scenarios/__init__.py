@@ -46,5 +46,4 @@ def load_builtin() -> None:
     if _LOADED:
         return
     from repro.scenarios import serving  # noqa: F401
-    from repro.scenarios import engine  # noqa: F401
     _LOADED = True

@@ -1,8 +1,7 @@
 """Headless scenario execution: resolve knobs, run, assert SLOs, emit.
 
 ``run_scenario`` is the one entry point every consumer shares — the
-benchmark CLIs (``benchmarks/backbone_serve.py``,
-``benchmarks/engine_scale.py``), the CI smoke loop
+benchmark CLI (``benchmarks/backbone_serve.py``), the CI smoke loop
 (``python -m repro.scenarios run <name>``), the sweep driver, and tests.
 A scenario's ``run`` callable receives a :class:`ScenarioContext` and
 returns its metrics payload; the runner then evaluates every declared

@@ -22,7 +22,7 @@ stay exactly as before for sequential callers.  Reads spanning several
 chunksets — even of *different blobs*, via ``read_items_detailed`` — take
 the **batched decode path**: chunksets with the same erasure pattern are
 Clay-decoded in one wide GF call (``ClayCode.decode_batch``) instead of
-one at a time.  Every decode, batched or not, goes through the GF matmul
+one at a time.  Every decode goes through the GF matmul
 ``kernels.ops.resolve_decode_matmul`` picks: the Pallas ``gf_matmul`` on
 the node's device when that is a TPU, numpy otherwise.
 
@@ -316,7 +316,6 @@ class RPCNode:
         sp_deposit: float = 10.0,
         transport=None,
         scheduler: HedgedScheduler | None = None,
-        batch_decode: bool = True,
         decode_matmul="auto",
         device=None,
         cache_ttl_ms: float | None = None,
@@ -332,7 +331,6 @@ class RPCNode:
         self.hedge = hedge
         self.transport = transport or DirectTransport(sps)
         self.scheduler = scheduler or HedgedScheduler(hedge=hedge)
-        self.batch_decode = batch_decode
         # the Clay-decode GF matmul: a backend name that kernels.ops
         # resolves for `device` (a jax.Device; None = JAX's first), a
         # callable, or None for the numpy path
@@ -361,10 +359,6 @@ class RPCNode:
         self._admitted = 0  # reads between admission and final decode
         self._inflight_fetches = 0  # live chunkset fetch tasks toward SPs
         self._ewma_fetch_ms: float | None = None  # congestion signal
-        # fast-path instrumentation: when a dict is assigned here, every
-        # _cache_put records the FIRST sim time each key became servable
-        # from cache — the cohort classifier's hit/coalesce boundary
-        self.cache_put_log: dict[tuple, float] | None = None
         self.stats = ReadStats()
         contract.register_rpc(rpc_id)
 
@@ -588,8 +582,6 @@ class RPCNode:
             return  # admission: oversized objects would evict the whole hot set
         expires = None if self.cache_ttl_ms is None else now_ms + self.cache_ttl_ms
         version = self.contract.placement_version.get(key, 0)
-        if self.cache_put_log is not None and key not in self.cache_put_log:
-            self.cache_put_log[key] = now_ms
         self._cache[key] = (decoded, expires, version)
         self._cache.move_to_end(key)
         if len(self._cache) > self._cache_size:
@@ -614,8 +606,8 @@ class RPCNode:
         Cache misses are *spawned* as independent fetch tasks (hedged
         fetches overlap -> each item's latency is its own slowest leg, and
         concurrent requests' fetches contend for the same SP disk slots and
-        NICs), then decoded through the batched Clay path when more than
-        one misses: chunksets of *different blobs* with the same erasure
+        NICs), then decoded together through the batched Clay path:
+        chunksets of *different blobs* with the same erasure
         pattern still stack into one wide GF matmul, so a `get_many`
         spanning requests amortizes kernel dispatch across all of them.
 
@@ -713,13 +705,9 @@ class RPCNode:
         if fetched:
             order = sorted(fetched)
             shard_sets = [fetched[key].shards for key in order]
-            batches = [shard_sets] if self.batch_decode else [[s] for s in shard_sets]
-            decoded = [
-                dec for batch in batches
-                for dec in self.layout.code.reconstruct_data_batch(
-                    batch, matmul=self.decode_matmul
-                )
-            ]
+            decoded = self.layout.code.reconstruct_data_batch(
+                shard_sets, matmul=self.decode_matmul
+            )
             self.stats.chunksets_decoded += len(order)
             if self.decode_matmul is None:
                 self.stats.chunksets_decoded_on_host += len(order)

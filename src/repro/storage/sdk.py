@@ -96,39 +96,6 @@ class ReadReceipt:
 
 
 @dataclasses.dataclass(frozen=True)
-class ReceiptBatch:
-    """Pooled receipts for one vectorized cohort (struct-of-arrays).
-
-    A million warm-cache reads do not need a million :class:`ReadReceipt`
-    objects and a million ``channel.pay()`` calls: the cohort fast path
-    (``repro.net.fastpath``) reports which requests stayed vectorized and
-    which node served each leg, and settlement charges each serving node's
-    channel ONCE with the numpy-summed total.  ``paid_by_node`` holds the
-    exact floats debited, so :meth:`ShelbySession.close` verifies
-    conservation against them without unpacking rows.  De-opted requests
-    (hedges, NACKs, cold-key leaders) still get individual receipts in
-    ``session.receipts``.
-    """
-
-    req_idx: np.ndarray  # rows into the replayed RequestBatch
-    blob_id: np.ndarray
-    offset: np.ndarray
-    length: np.ndarray
-    latency_ms: np.ndarray
-    nbytes: np.ndarray
-    paid: np.ndarray  # per-request total micropayment
-    paid_by_node: dict[str, float]  # rpc_id -> summed debit (one pay() each)
-
-    def __len__(self) -> int:
-        return int(self.req_idx.size)
-
-    @property
-    def total_paid(self) -> float:
-        # sorted so the float sum is independent of dict insertion order
-        return float(sum(self.paid_by_node[k] for k in sorted(self.paid_by_node)))
-
-
-@dataclasses.dataclass(frozen=True)
 class SessionSettlement:
     """Outcome of broadcasting every channel's freshest refund (§3.2).
 
@@ -170,7 +137,6 @@ class ShelbySession:
         self._price = client.read_price_per_byte
         self.channels: dict[str, MicropaymentChannel] = {}  # rpc_id -> channel
         self.receipts: list[ReadReceipt] = []
-        self.receipt_batches: list[ReceiptBatch] = []  # vectorized cohorts
         self.settlement: SessionSettlement | None = None
 
     # -- channels ------------------------------------------------------------------
@@ -262,17 +228,9 @@ class ShelbySession:
         ``shed=True`` (documented refusal: you asked, the fleet NACKed,
         you paid nothing), and its record is marked ``shed`` in the
         :class:`~repro.net.workloads.ReplayResult`.
-
-        Passing a :class:`~repro.net.workloads.RequestBatch` (and no
-        ``background``) routes through the cohort fast path instead:
-        returns ``(ReceiptBatch, ReplayResult)``, with de-opted requests'
-        individual receipts appended to ``session.receipts`` as usual.
         """
         self._settle_check()
-        from repro.net.workloads import RequestBatch, replay_open_loop
-
-        if isinstance(requests, RequestBatch) and background is None:
-            return self._replay_batch(requests, trace=trace, engine=engine)
+        from repro.net.workloads import replay_open_loop
 
         receipts: list[ReadReceipt | None] = [None] * len(requests)
 
@@ -307,71 +265,6 @@ class ShelbySession:
                                   background=background, trace=trace,
                                   engine=engine)
         return receipts, result
-
-    def _replay_batch(self, batch, *, trace: bool = False,
-                      engine: str | None = None):
-        """Cohort-fast replay of a :class:`RequestBatch` with settlement
-        done on arrays: each serving node's channel is debited ONCE with the
-        numpy-aggregated total of the vectorized cohort's pro-rata per-leg
-        payments — the same ``max(price * bytes * legs_on_node / legs,
-        1e-12)`` formula :meth:`_receipt_for` applies per request, charged
-        per cohort.  De-opted requests pay per-receipt via the task path."""
-        from repro.net.fastpath import replay_open_loop_fast
-
-        def on_served(i, req, sr):
-            self._receipt_for(sr)
-
-        def on_shed(i, req, nack_ms):
-            self.receipts.append(ReadReceipt(
-                blob_id=req.blob_id, offset=req.offset, length=req.length,
-                data=b"", latency_ms=nack_ms, payments={},
-                chunksets_by_node={}, shed=True,
-            ))
-
-        result = replay_open_loop_fast(self._fleet, batch, engine=engine,
-                                       on_served=on_served, on_shed=on_shed,
-                                       trace=trace)
-        co = result.cohort
-        paid_by_node: dict[str, float] = {}
-        n = len(batch)
-        if co is not None and co.vec_requests:
-            n_nodes = len(co.node_ids)
-            # collapse legs to (request, node) groups: the pro-rata share of
-            # a request's fee lands on each node in proportion to the legs
-            # (chunksets) that node served
-            pair = co.leg_req * n_nodes + co.leg_node
-            upair, counts = np.unique(pair, return_counts=True)
-            preq, pnode = upair // n_nodes, upair % n_nodes
-            legs_per_req = np.bincount(co.leg_req, minlength=n)
-            amounts = np.maximum(
-                self._price * batch.length[preq] * counts / legs_per_req[preq],
-                1e-12,
-            )
-            node_totals = np.bincount(pnode, weights=amounts, minlength=n_nodes)
-            for i in np.flatnonzero(node_totals).tolist():
-                total = float(node_totals[i])
-                self._channel(co.node_ids[i]).pay(total)
-                paid_by_node[co.node_ids[i]] = total
-            paid_req = np.bincount(preq, weights=amounts, minlength=n)
-            vec = co.vec_req_idx
-        else:
-            paid_req = np.zeros(n)
-            vec = np.empty(0, dtype=np.int64)
-        rows = result.batch
-        rb = ReceiptBatch(
-            req_idx=vec,
-            blob_id=batch.blob_id[vec].copy(),
-            offset=batch.offset[vec].copy(),
-            length=batch.length[vec].copy(),
-            latency_ms=(rows.latency_ms[vec].copy() if rows is not None
-                        else np.zeros(len(vec))),
-            nbytes=(co.vec_nbytes if co is not None and co.vec_nbytes is not None
-                    else np.zeros(len(vec), dtype=np.int64)),
-            paid=paid_req[vec],
-            paid_by_node=paid_by_node,
-        )
-        self.receipt_batches.append(rb)
-        return rb, result
 
     # -- DAS sampling (pay-per-sample light-client reads) --------------------------
     def sample_availability(
@@ -555,9 +448,6 @@ class ShelbySession:
         paid_by_node: dict[str, float] = {}
         for r in self.receipts:
             for rpc_id, amt in r.payments.items():
-                paid_by_node[rpc_id] = paid_by_node.get(rpc_id, 0.0) + amt
-        for rb in self.receipt_batches:  # vectorized cohorts: exact debits
-            for rpc_id, amt in rb.paid_by_node.items():
                 paid_by_node[rpc_id] = paid_by_node.get(rpc_id, 0.0) + amt
         for rpc_id, income in incomes.items():
             # tolerance tracks the deposit's float granularity: income is

@@ -1,7 +1,7 @@
-"""Engine equivalence: the calendar queue, the cohort fast path, and
-batched settlement must be *invisible* to every determinism digest.
+"""Engine equivalence: the calendar queue must be *invisible* to every
+determinism digest, and replayed sessions must settle deterministically.
 
-Three families of bars:
+Two families of bars:
 
 * ``CalendarQueue`` vs ``_BinaryHeap`` pop-order identity — a seeded
   hand-rolled property sweep (hypothesis is not in the image) over random
@@ -9,12 +9,9 @@ Three families of bars:
   zero-delay self-wakes, plus digest equality of full replays across the
   workload families (zipf streaming, DAS storm, membership churn,
   background planes) with ``engine="heap"`` vs ``engine="calendar"``.
-* ``replay_open_loop_fast`` vs task-per-request replay — byte-identical
-  digests and identical fleet/node counters on the single-chunkset worlds
-  the fast path guarantees float-exactness for, and loud, reasoned
-  fallbacks everywhere else.
-* Batched settlement — one-debit-per-node cohort payments conserve value
-  against the per-receipt task path and the contract's realized incomes.
+* Replay settlement — a paid session's open-loop replay digests equal on
+  fresh worlds, and every node's settled income equals what its receipts
+  say was paid.
 """
 from __future__ import annotations
 
@@ -25,13 +22,11 @@ from repro.core.contract import ShelbyContract
 from repro.core.placement import SPInfo
 from repro.net.backbone import Backbone
 from repro.net.events import CalendarQueue, EventLoop, _BinaryHeap
-from repro.net.fastpath import fastpath_fallback_reason, replay_open_loop_fast
 from repro.net.fleet import CacheAffinityPolicy, RPCFleet
 from repro.net.workloads import (
     das_storm,
     replay_open_loop,
     zipf_hotset,
-    zipf_hotset_batch,
 )
 from repro.core import audit as audit_mod
 from repro.storage.background import AuditPlane, RepairPlane
@@ -39,7 +34,7 @@ from repro.storage.blob import BlobLayout
 from repro.storage.das import DASSpec, extend_and_disperse_many
 from repro.storage.membership import ChurnSpec, MembershipPlane
 from repro.storage.repair import RepairCoordinator
-from repro.storage.rpc import AdmissionSpec, BackboneTransport, RPCNode
+from repro.storage.rpc import BackboneTransport, RPCNode
 from repro.storage.sdk import ShelbyClient
 from repro.storage.sp import BackgroundSpec, ServiceSpec, StorageProvider
 
@@ -231,10 +226,10 @@ def test_default_engine_is_calendar():
 
 
 # ---------------------------------------------------------------------------
-# cohort fast path vs task-per-request replay
+# replay settlement: determinism and value conservation
 # ---------------------------------------------------------------------------
-def _fast_world(*, num_rpcs=2, cache=64, admission=None):
-    """Single-chunkset blobs + whole-blob reads: the float-exact regime."""
+def _replay_world(*, num_rpcs=2, cache=64):
+    """Two cached RPC nodes over eight SPs, twelve single-chunkset blobs."""
     layout = BlobLayout(k=4, m=2, chunkset_bytes_target=64 * 1024)
     contract = ShelbyContract()
     bb = Backbone.mesh(3, base_latency_ms=4.0, gbps=10.0)
@@ -250,8 +245,7 @@ def _fast_world(*, num_rpcs=2, cache=64, admission=None):
         node = f"rpc{r}"
         bb.register_node(node, f"dc{r % 3}")
         rpcs.append(RPCNode(node, contract, sps, layout, cache_chunksets=cache,
-                            transport=BackboneTransport(sps, bb, node),
-                            admission=admission))
+                            transport=BackboneTransport(sps, bb, node)))
     bb.register_node("client", "dc0")
     fleet = RPCFleet(rpcs, CacheAffinityPolicy(), backbone=bb)
     client = ShelbyClient(contract, fleet, deposit=1e9)
@@ -263,99 +257,30 @@ def _fast_world(*, num_rpcs=2, cache=64, admission=None):
     return fleet, client, metas
 
 
-def _fast_batch(metas, n=1500, seed=3):
-    return zipf_hotset_batch(metas, clients=["client"], num_requests=n,
-                             read_bytes=64 * 1024, interarrival_ms=0.2,
-                             seed=seed, arrival="poisson")
-
-
-def test_fast_path_digest_and_counters_match_task_mode():
-    fleet_t, _, metas = _fast_world()
-    batch = _fast_batch(metas)
-    r_task = replay_open_loop(fleet_t, batch.to_requests())
-
-    fleet_f, _, _ = _fast_world()
-    r_fast = replay_open_loop_fast(fleet_f, batch)
-
-    assert r_fast.cohort.fallback_reason is None
-    assert r_fast.cohort.vec_requests > 0
-    assert r_fast.cohort.deopt_requests > 0  # cold keys de-opted to tasks
-    assert r_task.digest() == r_fast.digest()
-    # the digest covers per-request rows; the fleet/node books must agree too
-    assert fleet_t.routed == fleet_f.routed
-    assert fleet_t.chunkset_reads == fleet_f.chunkset_reads
-    assert fleet_t.bytes_served == fleet_f.bytes_served
-    assert ([n.stats.cache_hits for n in fleet_t.rpcs]
-            == [n.stats.cache_hits for n in fleet_f.rpcs])
-    assert ([n.stats.coalesced for n in fleet_t.rpcs]
-            == [n.stats.coalesced for n in fleet_f.rpcs])
-    assert (sorted(fleet_t.request_latencies_ms)
-            == sorted(fleet_f.request_latencies_ms))
-
-
-def test_fast_path_is_deterministic_across_replays():
-    _, _, metas = _fast_world()
-    batch = _fast_batch(metas)
-    digests = set()
+def test_replay_settlement_conserves_value_and_is_deterministic():
+    _, _, metas = _replay_world()
+    reqs = zipf_hotset(metas, clients=["client"], num_requests=1000,
+                       read_bytes=64 * 1024, interarrival_ms=0.2, seed=11,
+                       arrival="poisson")
+    runs = []
     for _ in range(2):
-        fleet, _, _ = _fast_world()
-        digests.add(replay_open_loop_fast(fleet, batch).digest())
-    assert len(digests) == 1
+        _, client, _ = _replay_world()
+        with client.session(deposit_per_node=1e6) as session:
+            receipts, result = session.replay(reqs)
+        runs.append((session, receipts, result))
 
-
-def test_fast_path_falls_back_with_a_reason():
-    # admission control individuates requests -> whole batch de-opts
-    fleet, _, metas = _fast_world(
-        admission=AdmissionSpec(max_queued_requests=64))
-    batch = _fast_batch(metas, n=200)
-    reason = fastpath_fallback_reason(fleet, batch)
-    assert reason is not None and "admission" in reason
-    res = replay_open_loop_fast(fleet, batch)
-    assert res.cohort.fallback_reason == reason
-    assert res.cohort.vec_requests == 0
-    assert res.cohort.deopt_requests == len(batch)
-    # the fallback replay is the task path: digest matches it exactly
-    fleet_t, _, _ = _fast_world(
-        admission=AdmissionSpec(max_queued_requests=64))
-    assert res.digest() == replay_open_loop(fleet_t, batch.to_requests()).digest()
-
-
-def test_fast_path_fallback_on_stateful_policy():
-    from repro.net.fleet import PowerOfTwoPolicy
-
-    fleet, _, metas = _fast_world()
-    fleet.policy = PowerOfTwoPolicy()
-    reason = fastpath_fallback_reason(fleet, _fast_batch(metas, n=50))
-    assert reason is not None and "stateful" in reason
-
-
-# ---------------------------------------------------------------------------
-# batched settlement conservation
-# ---------------------------------------------------------------------------
-def test_batched_settlement_conserves_value_vs_task_path():
-    fleet_t, client_t, metas = _fast_world()
-    batch = _fast_batch(metas, n=1000, seed=11)
-    with client_t.session(deposit_per_node=1e6) as s_task:
-        _, r_task = s_task.replay(batch.to_requests())
-        paid_task = s_task.total_paid
-    set_task = s_task.settlement
-
-    fleet_f, client_f, _ = _fast_world()
-    with client_f.session(deposit_per_node=1e6) as s_fast:
-        rb, r_fast = s_fast.replay(batch)
-        paid_fast = s_fast.total_paid
-    set_fast = s_fast.settlement
-
-    assert r_task.digest() == r_fast.digest()
-    assert len(rb) == r_fast.cohort.vec_requests
-    # value conservation: batched one-debit-per-node totals equal the task
-    # path's per-receipt debits, node by node
-    assert paid_fast == pytest.approx(paid_task, rel=1e-9)
-    for nid, income in set_task.node_income.items():
-        assert set_fast.node_income.get(nid, 0.0) == pytest.approx(income,
-                                                                   rel=1e-9)
-    # the cohort's recorded debits are exactly what the channels saw
-    assert (rb.total_paid + sum(r.total_paid for r in s_fast.receipts)
-            == pytest.approx(set_fast.total_node_income, abs=1e-9))
-    assert np.all(rb.paid > 0.0)
-    assert np.all(rb.latency_ms >= 0.0)
+    assert runs[0][2].digest() == runs[1][2].digest()
+    for session, receipts, _ in runs:
+        # settled income equals the receipts' payments, node by node
+        paid_by_node: dict[str, float] = {}
+        for r in session.receipts:
+            for nid, amount in r.payments.items():
+                paid_by_node[nid] = paid_by_node.get(nid, 0.0) + amount
+        income = session.settlement.node_income
+        assert set(income) == set(paid_by_node)
+        for nid, paid in paid_by_node.items():
+            assert income[nid] == pytest.approx(paid, rel=1e-9)
+        served = [r for r in receipts if r is not None and not r.shed]
+        assert served
+        assert all(r.total_paid > 0.0 for r in served)
+        assert all(r.latency_ms >= 0.0 for r in served)

@@ -110,7 +110,7 @@ def test_builtin_registry_contents():
     load_builtin()
     names = REGISTRY.names()
     for expected in ("serve_grid", "concurrent", "background", "churn",
-                     "das", "engine", "tune_admission"):
+                     "das", "tune_admission"):
         assert expected in names, names
     # sections are unique: two scenarios must never clobber one BENCH key
     sections = [sc.section for sc in REGISTRY]

@@ -250,7 +250,6 @@ class _Receipt:
 class _Session:
     def __init__(self, receipts, channels):
         self.receipts = receipts
-        self.receipt_batches = []
         self.channels = channels
 
 
