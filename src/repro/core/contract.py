@@ -17,6 +17,9 @@ import dataclasses
 import enum
 import hashlib
 from collections import defaultdict
+from collections.abc import Mapping
+
+import numpy as np
 
 from repro.core import audit as audit_mod
 from repro.core import commitments as cm
@@ -59,22 +62,51 @@ class Reassignment:
     new_sp: int
 
 
+class SharePlacement(Mapping):
+    """A DAS square's placement, (row, col) -> sp_id, read-only over a
+    (side, side) array of SP ids; iterates coordinates row by row."""
+
+    def __init__(self, sp_ids: np.ndarray):
+        self.sp_ids = np.array(sp_ids, dtype=np.int64)
+        self.sp_ids.flags.writeable = False
+
+    def __getitem__(self, coord: tuple[int, int]) -> int:
+        side = self.sp_ids.shape[0]
+        try:
+            row, col = coord
+            if 0 <= row < side and 0 <= col < side:
+                return int(self.sp_ids[row, col])
+        except (TypeError, ValueError):
+            pass
+        raise KeyError(coord)
+
+    def __iter__(self):
+        side = self.sp_ids.shape[0]
+        return ((r, c) for r in range(side) for c in range(side))
+
+    def __len__(self) -> int:
+        return self.sp_ids.size
+
+
 @dataclasses.dataclass(frozen=True)
 class DASRecord:
     """On-chain record of a blob's 2-D DAS extension (see core/extend2d.py).
 
     Only the DAS root and the share placement live on chain — the row and
-    column trees stay with the storage providers, who attach per-share
-    Merkle paths to sampled reads.  ``proof_bytes`` is the fixed modeled
-    wire size of one share proof (constant for a given ``side``), used by
-    transports to bill proof bandwidth without shipping the object graph.
+    column trees stay with the storage providers, who keep each blob's
+    shares and Merkle paths as arrays and attach a share's paths to a
+    sampled read.  ``placement`` is a :class:`SharePlacement` over the
+    square's (side, side) array of SP ids.  ``proof_bytes`` is the fixed
+    modeled wire size of one share proof (constant for a given ``side``),
+    used by transports to bill proof bandwidth without shipping the
+    object graph.
     """
 
     blob_id: int
     side: int  # 2k
     share_bytes: int
     das_root: bytes
-    placement: dict[tuple[int, int], int]  # (row, col) -> sp_id
+    placement: SharePlacement  # (row, col) -> sp_id
     proof_bytes: int
 
 

@@ -32,6 +32,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import itertools
 
 import numpy as np
 
@@ -72,6 +73,37 @@ class ShareProof:
         return 8 + self.leaf_path.nbytes + len(self.axis_root) + self.root_path.nbytes
 
 
+@dataclasses.dataclass(frozen=True, eq=False)
+class LinePaths:
+    """The half of every share proof of one square that depends only on
+    the line the share is proved along, as tables indexed by line (rows
+    0..side-1, then columns side..2*side-1): each line's root and that
+    root's path to the DAS root."""
+
+    side: int
+    axis_roots: tuple[bytes, ...]
+    root_paths: tuple[cm.MerkleProof, ...]
+
+    def proof(self, row: int, col: int, leaf_path: np.ndarray) -> ShareProof:
+        """Share (row, col)'s proof, given its (depth, 32) leaf path from
+        :meth:`CommittedSquare.prove_all`."""
+        if (row + col) % 2 == 0:
+            axis, index, line = "row", col, row
+        else:
+            axis, index, line = "col", row, self.side + col
+        return ShareProof(
+            row=row, col=col, axis=axis, axis_root=self.axis_roots[line],
+            leaf_path=cm.MerkleProof(index=index, path=_hashes(leaf_path)),
+            root_path=self.root_paths[line],
+        )
+
+
+def _hashes(nodes: np.ndarray) -> tuple[bytes, ...]:
+    """(n, 32) uint8 -> n 32-byte hashes."""
+    flat = nodes.tobytes()
+    return tuple([flat[i : i + 32] for i in range(0, len(flat), 32)])
+
+
 @dataclasses.dataclass(frozen=True)
 class SquareCommitment:
     side: int
@@ -109,6 +141,41 @@ class CommittedSquare:
 
     def share(self, row: int, col: int) -> np.ndarray:
         return self.ext[row, col]
+
+    def prove_all(self) -> tuple[np.ndarray, LinePaths]:
+        """Every share's proof as arrays: one gather over the trees' nodes.
+
+        Share (r, c) is proved along its row when ``(r + c) % 2 == 0``,
+        else along its column.  Returns the leaf paths, (side, side,
+        depth, 32): the sibling of share (r, c) at each level of its axis
+        tree, bytes for bytes the ``path`` of :meth:`prove`; and the
+        :class:`LinePaths` of the square, whose :meth:`LinePaths.proof`
+        turns one share's leaf path into the :class:`ShareProof` that
+        :meth:`prove` builds.
+        """
+        side = self.commitment.side
+        depth = len(self.row_trees[0].levels) - 1
+        # every node below the roots: row trees level by level, then columns
+        nodes = np.frombuffer(b"".join(itertools.chain.from_iterable(
+            t.levels[j] for trees in (self.row_trees, self.col_trees)
+            for j in range(depth) for t in trees
+        )), np.uint8).reshape(-1, 32)
+        width = np.array([len(level) for level in self.row_trees[0].levels[:depth]])
+        start = np.concatenate([[0], np.cumsum(side * width)])
+        # for each share (r, c) and level j: its tree, its leaf index there
+        r = np.arange(side)[:, None, None]
+        c = np.arange(side)[None, :, None]
+        j = np.arange(depth)
+        on_row = (r + c) % 2 == 0
+        tree = np.where(on_row, r, c)
+        at = np.where(on_row, c, r)
+        leaf_paths = nodes[np.where(on_row, 0, start[-1]) + start[j] + tree * width
+                           + ((at >> j) ^ 1)]
+        return leaf_paths, LinePaths(
+            side=side,
+            axis_roots=self.commitment.row_roots + self.commitment.col_roots,
+            root_paths=tuple(self.das_tree.prove(line) for line in range(2 * side)),
+        )
 
     def prove(self, row: int, col: int, axis: str = "row") -> ShareProof:
         side = self.commitment.side

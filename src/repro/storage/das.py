@@ -10,7 +10,10 @@ serving stack:
   variants stack MANY blobs into the same call), Merkle-commit rows,
   columns and the DAS root, place every share on a contract-drawn SP
   (epoch-seeded, like chunk placement), and publish a
-  :class:`~repro.core.contract.DASRecord` on chain.
+  :class:`~repro.core.contract.DASRecord` on chain.  Shares and their
+  proofs travel as arrays: one draw places the whole square, and each
+  SP keeps its part of a blob as arrays of coordinates, shares and
+  Merkle paths.
 * :class:`LightClientSampler` / ``ShelbySession.sample_availability`` —
   each epoch draw ``s`` uniform share coordinates per blob, fetch them
   through the fleet as tiny paid reads (share + commitment path over the
@@ -37,7 +40,7 @@ import numpy as np
 
 from repro.core import extend2d
 from repro.core import placement as placement_mod
-from repro.core.contract import DASRecord, ShelbyContract
+from repro.core.contract import DASRecord, SharePlacement, ShelbyContract
 
 
 @dataclasses.dataclass(frozen=True)
@@ -138,38 +141,47 @@ def extend_and_disperse_many(
 ) -> list[DASRecord]:
     """Extend + commit + place MANY blobs' squares; the two RS extension
     stages run as ONE wide GF matmul each across all of them (the
-    small-and-wide kernel regime — see ``benchmarks/gf_kernel.py``)."""
+    small-and-wide kernel regime — see ``benchmarks/gf_kernel.py``).
+
+    Placement and proofs are whole-array passes over each square: one
+    draw of every share's SP (row-major, the same sequence as one scalar
+    draw a share), every share's proof from
+    :meth:`~repro.core.extend2d.CommittedSquare.prove_all`, and one
+    ``store_shares`` call per holding SP with its shares and leaf paths
+    as arrays.  The record's placement is a read-only view of the
+    (side, side) array of SP ids."""
     lay = spec.layout()
+    side = lay.side
     squares = [lay.pad_square(data, spec.share_bytes) for _, data in blobs]
     exts = lay.extend_batch(squares, matmul=matmul)
-    active = [info.sp_id for info in contract.active_sps()]
-    if not active:
+    active = np.array([info.sp_id for info in contract.active_sps()], dtype=np.int64)
+    if not active.size:
         raise RuntimeError("no active SPs to hold DAS shares")
     records = []
     for (blob_id, _), ext in zip(blobs, exts):
         csq = extend2d.commit_square(ext)
+        leaf_paths, lines = csq.prove_all()
         rng = placement_mod._rng(
             contract.epoch_seed(contract.epoch), b"das", blob_id
         )
-        placement: dict[tuple[int, int], int] = {}
-        proof_bytes = None
-        for r in range(lay.side):
-            for c in range(lay.side):
-                sp_id = int(active[int(rng.integers(0, len(active)))])
-                placement[(r, c)] = sp_id
-                proof = csq.prove(r, c, axis="row" if (r + c) % 2 == 0 else "col")
-                if proof_bytes is None:
-                    proof_bytes = proof.nbytes
-                sps[sp_id].store_share(blob_id, r, c, csq.share(r, c), proof)
+        sp_ids = active[rng.integers(0, active.size, size=side * side)]
+        # flat coordinates grouped by SP, ascending within each group
+        order = np.argsort(sp_ids, kind="stable")
+        holders, starts = np.unique(sp_ids[order], return_index=True)
+        shares = ext.reshape(side * side, -1)
+        paths = leaf_paths.reshape(side * side, -1, 32)
+        for sp_id, index in zip(holders.tolist(), np.split(order, starts[1:])):
+            sps[sp_id].store_shares(blob_id, index, shares[index], paths[index], lines)
         record = DASRecord(
             blob_id=blob_id,
-            side=lay.side,
+            side=side,
             share_bytes=spec.share_bytes,
             das_root=csq.commitment.das_root,
-            placement=placement,
+            placement=SharePlacement(sp_ids.reshape(side, side)),
             proof_bytes=(
                 spec.proof_bytes_per_share
-                if spec.proof_bytes_per_share is not None else proof_bytes
+                if spec.proof_bytes_per_share is not None
+                else lines.proof(0, 0, leaf_paths[0, 0]).nbytes
             ),
         )
         contract.register_das(record)

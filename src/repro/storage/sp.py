@@ -21,6 +21,7 @@ from collections import OrderedDict
 import numpy as np
 
 from repro.core import commitments as cm
+from repro.core import extend2d
 from repro.core.audit import Challenge, Scoreboard
 from repro.core.contract import ShelbyContract
 
@@ -89,6 +90,16 @@ class ServiceSpec:
     background: BackgroundSpec = dataclasses.field(default_factory=BackgroundSpec)
 
 
+@dataclasses.dataclass(frozen=True, eq=False)
+class _ShareBlock:
+    """One SP's shares of one blob's DAS square, kept as arrays."""
+
+    index: np.ndarray  # (n,) sorted flat coordinates row * side + col
+    shares: np.ndarray  # (n, share_bytes) uint8
+    leaf_paths: np.ndarray  # (n, depth, 32) uint8: each share's path in its axis tree
+    lines: extend2d.LinePaths  # the square's axis roots and their DAS-tree paths
+
+
 @dataclasses.dataclass(frozen=True)
 class AuditProof:
     """What an auditee broadcasts (§4.1): the sample + its Merkle proof."""
@@ -111,11 +122,12 @@ class StorageProvider:
         self._chunks: dict[tuple[int, int, int], np.ndarray] = {}
         # DAS share plane (core/extend2d.py): shares are stored alongside —
         # not inside — `_chunks`, so chunk audits and repair accounting are
-        # untouched by the sampling regime.  Withheld coordinates keep their
-        # bytes (the adversary HAS the data, it just won't serve it — the
-        # case chunk-possession audits structurally cannot catch).
-        self._das_shares: dict[tuple[int, int, int], np.ndarray] = {}
-        self._das_proofs: dict[tuple[int, int, int], object] = {}
+        # untouched by the sampling regime.  One `_ShareBlock` a blob holds
+        # this SP's shares of its square and their proofs as arrays.
+        # Withheld coordinates keep their bytes (the adversary HAS the data,
+        # it just won't serve it — the case chunk-possession audits
+        # structurally cannot catch).
+        self._das_blocks: dict[int, _ShareBlock] = {}
         self._das_withheld: set[tuple[int, int, int]] = set()
         self._trees: OrderedDict[tuple[int, int, int], cm.MerkleTree] = OrderedDict()
         self._tree_cache = tree_cache
@@ -193,14 +205,15 @@ class StorageProvider:
         return data, self.service_ms()
 
     # -- DAS share plane (paid tiny reads, core/extend2d.py) -----------------------
-    def store_share(self, blob_id: int, row: int, col: int, share: np.ndarray,
-                    proof) -> bool:
-        """Accept one DAS share + its pre-built commitment proof."""
+    def store_shares(self, blob_id: int, index: np.ndarray, shares: np.ndarray,
+                     leaf_paths: np.ndarray, lines: extend2d.LinePaths) -> bool:
+        """Accept this SP's part of a blob's DAS square, replacing any earlier
+        part: the sorted flat coordinates ``row * side + col``, the shares
+        (n, share_bytes) and leaf paths (n, depth, 32) in that order, and
+        the square's :class:`~repro.core.extend2d.LinePaths`."""
         if self.behavior.crashed:
             return False
-        key = (blob_id, row, col)
-        self._das_shares[key] = np.array(share, dtype=np.uint8)
-        self._das_proofs[key] = proof
+        self._das_blocks[blob_id] = _ShareBlock(index, shares, leaf_paths, lines)
         return True
 
     def withhold_share(self, blob_id: int, row: int, col: int) -> None:
@@ -208,7 +221,7 @@ class StorageProvider:
         self._das_withheld.add((blob_id, row, col))
 
     def stored_shares(self) -> int:
-        return len(self._das_shares)
+        return sum(len(block.index) for block in self._das_blocks.values())
 
     def serve_share(self, blob_id: int, row: int, col: int):
         """Returns (share_bytes, proof, latency_ms) or None.
@@ -218,16 +231,20 @@ class StorageProvider:
         withholding or corrupting SP earns nothing from the sample — and
         the refusal itself IS the availability signal.
         """
-        if self.behavior.crashed:
+        if self.behavior.crashed or (blob_id, row, col) in self._das_withheld:
             return None
-        key = (blob_id, row, col)
-        if key not in self._das_shares or key in self._das_withheld:
+        block = self._das_blocks.get(blob_id)
+        if block is None or not (0 <= row < block.lines.side and 0 <= col < block.lines.side):
             return None
-        data = self._das_shares[key]
+        flat = row * block.lines.side + col
+        i = int(block.index.searchsorted(flat))
+        if i == len(block.index) or block.index[i] != flat:
+            return None
+        data = block.shares[i]
         if self.behavior.corrupt:
             data = data.copy()
             data.reshape(-1)[0] ^= 0xFF
-        return data, self._das_proofs[key], self.service_ms()
+        return data, block.lines.proof(row, col, block.leaf_paths[i]), self.service_ms()
 
     def serve_subchunks(self, blob_id: int, chunkset: int, chunk: int, ids: list[int]):
         """MSR repair helper read: only the requested sub-chunks (planes)."""
@@ -326,6 +343,5 @@ class StorageProvider:
     def wipe(self):
         self._chunks.clear()
         self._trees.clear()
-        self._das_shares.clear()
-        self._das_proofs.clear()
+        self._das_blocks.clear()
         self._das_withheld.clear()

@@ -114,6 +114,101 @@ def test_proof_rejects_tamper_and_replay():
                                      csq.share(2, 5).tobytes(), proof)
 
 
+# -- placement and proofs as arrays --------------------------------------------
+SQUARE_KS = [2, 3, 4, 16]  # 3: a side of 6, whose trees pad to 8 leaves
+
+
+def _dispersed(k, *, crash=None, seed=0):
+    """One blob's square placed on 6 SPs; ``crash`` goes down first."""
+    spec = DASSpec(k=k, share_bytes=64)
+    contract, sps, _, (blob_id,) = das._mini_world(
+        6, DASSpec(k=k, share_bytes=64, extension=False), 1, seed)
+    if crash is not None:
+        sps[crash].crash()
+    square = _square(k=k, seed=seed)
+    rec = das.extend_and_disperse(contract, sps, blob_id, square.tobytes(), spec)
+    return contract, sps, rec, commit_square(Extend2D(k=k).extend(square))
+
+
+def _coords(side):
+    return [(r, c) for r in range(side) for c in range(side)]
+
+
+@pytest.mark.parametrize("k", SQUARE_KS)
+def test_array_draw_places_like_one_scalar_draw_a_share(k):
+    contract, sps, rec, _ = _dispersed(k)
+    active = [info.sp_id for info in contract.active_sps()]
+    rng = das.placement_mod._rng(contract.epoch_seed(contract.epoch), b"das", rec.blob_id)
+    expected = {(r, c): active[int(rng.integers(0, len(active)))]
+                for r, c in _coords(rec.side)}
+    assert dict(rec.placement) == expected
+    assert sum(sp.stored_shares() for sp in sps.values()) == rec.side ** 2
+
+
+@pytest.mark.parametrize("k", SQUARE_KS)
+def test_every_share_is_served_with_the_proof_the_square_gives(k):
+    _, sps, rec, csq = _dispersed(k)
+    assert rec.das_root == csq.commitment.das_root
+    assert rec.proof_bytes == csq.prove(0, 0, axis="row").nbytes
+    for r, c in _coords(rec.side):
+        share, proof, _ = sps[rec.placement[(r, c)]].serve_share(rec.blob_id, r, c)
+        assert share.tobytes() == csq.share(r, c).tobytes()
+        assert proof == csq.prove(r, c, axis="row" if (r + c) % 2 == 0 else "col")
+        assert extend2d.verify_share(rec.das_root, rec.side, share.tobytes(), proof)
+
+
+@pytest.mark.parametrize("k", SQUARE_KS)
+def test_an_sp_crashed_at_put_time_holds_none_of_its_shares(k):
+    _, sps, rec, _ = _dispersed(k, crash=1)
+    sps[1].recover()
+    assert sps[1].stored_shares() == 0
+    for r, c in _coords(rec.side):
+        got = sps[rec.placement[(r, c)]].serve_share(rec.blob_id, r, c)
+        assert (got is None) == (rec.placement[(r, c)] == 1)
+    held = sum(sp_id != 1 for sp_id in rec.placement.values())
+    assert sum(sp.stored_shares() for sp in sps.values()) == held
+
+
+@pytest.mark.parametrize("k", SQUARE_KS)
+def test_withholding_and_wipe_silence_shares(k):
+    contract, sps, rec, _ = _dispersed(k)
+
+    def silent():
+        return {(r, c) for r, c in _coords(rec.side)
+                if sps[rec.placement[(r, c)]].serve_share(rec.blob_id, r, c) is None}
+
+    w = seed_withholding(contract, sps, rec.blob_id, 0.25, seed=1)
+    assert w == round(0.25 * rec.side ** 2) and len(silent()) == w
+    withheld = silent()
+    sps[2].wipe()
+    assert sps[2].stored_shares() == 0
+    assert silent() == withheld | {rc for rc, sp_id in rec.placement.items() if sp_id == 2}
+    for sp in sps.values():  # out of the square: nothing aliases another share
+        assert sp.serve_share(rec.blob_id, 0, rec.side) is None
+        assert sp.serve_share(rec.blob_id, -1, 0) is None
+
+
+@pytest.mark.parametrize("k", SQUARE_KS)
+def test_placement_reads_as_the_dict_did(k):
+    contract, _, rec, _ = _dispersed(k)
+    placement = rec.placement
+    side = rec.side
+    assert len(placement) == side * side
+    assert list(placement) == _coords(side)  # row-major, the dict's insertion order
+    assert list(placement.items()) == [(rc, placement[rc]) for rc in _coords(side)]
+    assert all(isinstance(sp_id, int) for sp_id in placement.values())
+    assert set(placement.values()) <= {info.sp_id for info in contract.active_sps()}
+    assert (side - 1, side - 1) in placement
+    for missing in [(side, 0), (0, side), (-1, 0), (0,), "x"]:
+        assert missing not in placement
+        with pytest.raises(KeyError):
+            placement[missing]
+    with pytest.raises(TypeError):
+        placement[(0, 0)] = 1
+    with pytest.raises(ValueError):
+        placement.sp_ids[0, 0] = 1
+
+
 # -- detection: measured vs analytic -----------------------------------------
 def test_detection_matches_analytic_across_seeds_and_fractions():
     # >= 3 fractions x >= 3 seeds; exact-count withholding + with-
